@@ -35,7 +35,7 @@ def _inputs(genome=10_000):
     rs = simulate_reads(g, depth=12, mean_len=900, std_len=120,
                         error_rate=0.03, seed=4)
     km = extract_kmers(jnp.asarray(rs.codes), jnp.asarray(rs.lengths), k=15)
-    kc = count_and_select(km, lower=2, upper=24)
+    kc = count_and_select(km, k=15, lower=2, upper=24)
     a, at, _, _ = build_matrices(kc, n_reads=rs.n_reads, m_capacity=1 << 14,
                                  read_capacity=128, kmer_capacity=24)
     return a, at, kc, rs

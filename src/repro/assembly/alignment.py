@@ -37,6 +37,16 @@ class Extension(NamedTuple):
     bj: jnp.ndarray  # chars consumed of b
 
 
+def _varying_like(tree, *refs):
+    """Cast ``tree``'s leaves to vary over every manual mesh axis any of
+    ``refs`` varies over, so loop carries seeded from constants type-match
+    the carries the body computes from ``refs`` inside a ``shard_map``."""
+    axes = tuple(sorted(set().union(*(jax.typeof(r).vma for r in refs))))
+    if not axes:
+        return tree
+    return jax.tree.map(lambda x: jax.lax.pcast(x, axes, to="varying"), tree)
+
+
 def _fetch(codes, base, step, t, limit):
     """codes[base + step*t] with validity t < limit."""
     idx = base + step * t
@@ -99,9 +109,10 @@ def xdrop_extend(
 
     h1 = jnp.full((w,), NEG)  # wavefront s−1 (empty)
     h2 = jnp.where(offs == 0, 0, NEG)  # virtual origin at s−2
-    init = (
-        jnp.int32(0), h1, h2, jnp.int32(0), jnp.int32(0), jnp.int32(0),
-        jnp.bool_(True),
+    init = _varying_like(
+        (jnp.int32(0), h1, h2, jnp.int32(0), jnp.int32(0), jnp.int32(0),
+         jnp.bool_(True)),
+        a, base_a, step_a, len_a, b, base_b, step_b, len_b,
     )
     _, _, _, best, bi, bj, _ = jax.lax.while_loop(cond_fn, step_fn, init)
     return Extension(score=best, ai=bi, bj=bj)

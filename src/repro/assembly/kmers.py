@@ -44,20 +44,20 @@ def revcomp(codes: jnp.ndarray, length: jnp.ndarray | int) -> jnp.ndarray:
     return jnp.where(idx >= 0, out, 0).astype(jnp.uint8)
 
 
-def _pack(window_codes: jnp.ndarray, k: int) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Pack (..., k) codes into (hi, lo) int32 words, 15 bases per word,
-    big-endian within the word so (hi, lo) ordering is lexicographic."""
+def _pack(window_codes, k: int) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Pack k int32 code arrays (base 0 first) into (hi, lo) int32 words,
+    15 bases per word, big-endian within the word so (hi, lo) ordering is
+    lexicographic."""
     assert k <= 30, "k ≤ 30 supported (2×15 bases in int32)"
     k_hi = min(k, 15)
-    c = window_codes.astype(jnp.int32)
-    hi = jnp.zeros(c.shape[:-1], jnp.int32)
+    hi = jnp.zeros(window_codes[0].shape, jnp.int32)
     for t in range(k_hi):
-        hi = hi * 4 + c[..., t]
+        hi = hi * 4 + window_codes[t]
     # left-align so shorter-than-15 prefixes still compare lexicographically
     hi = hi * (4 ** (15 - k_hi))
-    lo = jnp.zeros(c.shape[:-1], jnp.int32)
+    lo = jnp.zeros(window_codes[0].shape, jnp.int32)
     for t in range(k_hi, k):
-        lo = lo * 4 + c[..., t]
+        lo = lo * 4 + window_codes[t]
     lo = lo * (4 ** (15 - max(0, k - 15)))
     return hi, lo
 
@@ -66,20 +66,23 @@ def _pack(window_codes: jnp.ndarray, k: int) -> tuple[jnp.ndarray, jnp.ndarray]:
 def extract_kmers(codes: jnp.ndarray, lengths: jnp.ndarray, *, k: int):
     """All canonical k-mer instances of each read.
 
-    Returns dict with (n, P) arrays where P = L_max − k + 1:
+    Returns dict with (n, P) arrays where P = L_max − k + 1 rounded up to a
+    multiple of 128 (flattening (n, P) is then cheap for XLA's TPU compiler;
+    an unaligned P cost it minutes at bacterial sizes):
       hi, lo  — packed canonical k-mer
       strand  — 0 if canonical == forward k-mer else 1
       pos     — start position in the (forward) read
       valid   — position in range
     """
     n, lmax = codes.shape
-    p = lmax - k + 1
+    p = -(-max(lmax - k + 1, 1) // 128) * 128
     pos = jnp.arange(p)
-    win = pos[:, None] + jnp.arange(k)[None, :]  # (P, k)
-    w = codes[:, win]  # (n, P, k)
-    fwd_hi, fwd_lo = _pack(w, k)
-    wrc = (COMPLEMENT - w[..., ::-1].astype(jnp.int32)).astype(jnp.uint8)
-    rc_hi, rc_lo = _pack(wrc, k)
+    c = jnp.pad(codes.astype(jnp.int32), ((0, 0), (0, p + k - 1 - lmax)))
+    # base t of every window is the column slice c[:, t : t + P]; packing
+    # slice by slice never materializes the (n, P, k) window tensor
+    base = [c[:, t : t + p] for t in range(k)]
+    fwd_hi, fwd_lo = _pack(base, k)
+    rc_hi, rc_lo = _pack([COMPLEMENT - b for b in reversed(base)], k)
     fwd_smaller = (fwd_hi < rc_hi) | ((fwd_hi == rc_hi) & (fwd_lo <= rc_lo))
     hi = jnp.where(fwd_smaller, fwd_hi, rc_hi)
     lo = jnp.where(fwd_smaller, fwd_lo, rc_lo)

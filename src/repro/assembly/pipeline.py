@@ -25,7 +25,11 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
-from ..core.backend import resolve_backend, resolve_distribution
+from ..core.backend import (
+    recording_impls,
+    resolve_backend,
+    resolve_distribution,
+)
 from ..core.semiring import overlap_semiring
 from ..core.spgemm import spgemm
 from ..core.spmat import map_row_blocks, next_pow2
@@ -42,6 +46,13 @@ from .contig_gen import generate_contigs
 from .contigs import contig_stats
 from .counter import build_matrices, count_and_select
 from .kmers import extract_kmers, revcomp
+
+
+# candidates (rows × K_A × K_B) the local overlap SpGEMM expands and sorts
+# at once, at most.  At K_A·K_B = 8960 that is 4096-row blocks, for which
+# XLA's v5e compile reserves 2.1 GB of temporaries (4.7 GB for 10,000 rows
+# unblocked; an E. coli-length run unblocked would not fit 16 GB of HBM).
+SPGEMM_CANDIDATES = 1 << 26
 
 
 @dataclasses.dataclass
@@ -132,7 +143,7 @@ def assemble(codes, lengths, cfg: PipelineConfig = PipelineConfig()) -> Assembly
     # so every AssemblyResult.stats carries the peak_hbm_bytes family —
     # HBM capacity is the genome-size ceiling, and the watermark is what the
     # bench trajectory and the regression gate track
-    with watermark() as wm:
+    with watermark() as wm, recording_impls() as impls:
         tracer = Tracer(annotate=True) if cfg.trace else None
         if tracer is None:
             res = _assemble(codes, lengths, cfg, tracer=None)
@@ -145,6 +156,8 @@ def assemble(codes, lengths, cfg: PipelineConfig = PipelineConfig()) -> Assembly
         "peak_hbm_bytes": wm.peak_hbm_bytes,
         "hbm_bytes_in_use": wm.hbm_bytes_in_use,
         "hbm_source": wm.source,
+        # what actually ran for every dispatched op (core/backend.py)
+        "op_impls": {op: "+".join(sorted(v)) for op, v in impls.items()},
     }, context="assemble"))
     return res
 
@@ -163,7 +176,8 @@ def _assemble(codes, lengths, cfg: PipelineConfig, *, tracer) -> AssemblyResult:
     with _tic(timings, "CountKmer") as sp:
         kmers = extract_kmers(codes, lengths, k=cfg.k)
         kc = sp.set_output(
-            count_and_select(kmers, lower=cfg.lower, upper=cfg.upper)
+            count_and_select(kmers, k=cfg.k, lower=cfg.lower,
+                             upper=cfg.upper)
         )
     metrics.emit_many({
         "m_reliable": int(kc.m_reliable),
@@ -215,10 +229,18 @@ def _assemble(codes, lengths, cfg: PipelineConfig, *, tracer) -> AssemblyResult:
             metrics.emit("overlap_distribution", "shard_map")
             metrics.emit_many(summa_stats)
         else:
+            # the candidate expand/sort buffer holds rows·K_A·K_B entries;
+            # past SPGEMM_CANDIDATES it runs in row blocks (per-row
+            # identical) so a bacterial genome's buffer fits in HBM
+            per_row = a.cols.shape[1] * at.cols.shape[1]
+            row_chunk = 1 << (max(1, SPGEMM_CANDIDATES // per_row)
+                              .bit_length() - 1)
             c_mat, ovf_c = spgemm(
-                a, at, semiring=overlap_semiring, capacity=cfg.overlap_capacity
+                a, at, semiring=overlap_semiring,
+                capacity=cfg.overlap_capacity, row_chunk=row_chunk,
             )
             metrics.emit("overlap_distribution", "gspmd")
+            metrics.emit("spgemm_row_chunk", min(row_chunk, int(n)))
         sp.set_output(c_mat.cols)
     metrics.seed_zero("summa_exchange")
     metrics.emit("overflow_C", int(ovf_c))

@@ -42,12 +42,17 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
-from ..compat import shard_map
 from ..obs import validated
 from ..obs.trace import span
-from .backend import resolve_backend
+from .backend import (
+    default_impl,
+    record_impl,
+    resolve_backend,
+    resolve_interpret,
+)
 from .components_dist import default_row_mesh, infer_row_axes
 
 #: arrays of a PairAlignment result (score, bi, ei, bj, ej) — the scatter
@@ -147,11 +152,16 @@ def _align_program(
         return full
 
     cspec = P(row_axes)
+    # Pallas interpret mode evaluates the kernel body op by op, binding its
+    # literals unvarying next to the region's varying inputs, which the
+    # varying-axes check rejects; the compiled kernel and the reference
+    # extension both type-check, so only that combination turns it off.
+    check_vma = not (backend == "pallas" and resolve_interpret("auto"))
     fm = jax.jit(
         shard_map(
             f, mesh=mesh,
             in_specs=(cspec,) * (1 + len(_CAND_KEYS)),
-            out_specs=P(),
+            out_specs=P(), check_vma=check_vma,
         )
     )
     return fm, acct
@@ -223,6 +233,8 @@ def align_bucket_shard_map(
 
     from ..assembly.alignment import PairAlignment
 
+    # the cached program dispatches xdrop_extend only when first traced
+    record_impl("xdrop_extend", default_impl(resolve_backend(backend)))
     res = PairAlignment(*(full[t, :bucket] for t in range(ALIGN_OUTPUTS)))
     stats = validated({
         "exchange_words_align": acct["words"],

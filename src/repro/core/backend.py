@@ -7,9 +7,13 @@ implementation (the oracle) and a Pallas TPU *kernel*.  This module is the
 single seam that decides, per op, which one runs:
 
   * ``"reference"`` — the jnp oracle.  Always available, runs anywhere.
-  * ``"pallas"``    — the Pallas kernel.  Compiled on TPU; on other platforms
-    it runs in interpret mode (bit-identical semantics, no speedup) so parity
-    tests and CI exercise the exact kernel code path.
+  * ``"pallas"``    — the Pallas kernel.  Compiled by the TPU compiler
+    (Mosaic) on TPU — every kernel's compile for a v5e chip is a test in
+    ``tests/test_chip_compile.py``; on other platforms it runs in interpret
+    mode (bit-identical semantics, no speedup) so parity tests and CI
+    exercise the exact kernel code path.  One op, ``spgemm_ring_stages``,
+    has no TPU lowering yet and runs its oracle there (recorded, see
+    below).
   * ``"auto"``      — platform detection: ``"pallas"`` (compiled) when the
     default JAX backend is TPU, ``"reference"`` elsewhere.
 
@@ -63,6 +67,14 @@ Current ops
     (``tests/test_kernels.py``), and ``core.summa.summa_ring`` dispatches
     between them.
 
+Which implementation ran
+------------------------
+Every dispatched call records what actually ran for its op — ``"reference"``,
+``"pallas"`` (compiled), ``"pallas-interpret"``, or the reason an
+implementation took its oracle instead (:func:`note_impl`) — into the
+innermost :func:`recording_impls` log.  ``assemble`` opens one per run and
+reports it as ``AssemblyResult.stats["op_impls"]``, so no fallback is silent.
+
 Distribution axis
 -----------------
 Orthogonal to the backend axis, the device contig path has a
@@ -76,8 +88,10 @@ knob the same way ``resolve_backend`` does.
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import functools
-from typing import Callable, Dict, Tuple
+from typing import Callable, Dict, Iterator, List, Optional, Set, Tuple
 
 import jax
 
@@ -88,6 +102,15 @@ BACKENDS = ("auto", "reference", "pallas")
 DISTRIBUTIONS = ("gspmd", "shard_map")
 
 _REGISTRY: Dict[Tuple[str, str], Callable] = {}
+
+# op -> implementations that ran, for the innermost recording_impls() block
+_IMPL_LOG: contextvars.ContextVar[Optional[Dict[str, Set[str]]]] = (
+    contextvars.ContextVar("op_impl_log", default=None)
+)
+# notes an implementation makes about the call it is serving
+_CALL_NOTES: contextvars.ContextVar[Optional[List[str]]] = (
+    contextvars.ContextVar("op_call_notes", default=None)
+)
 
 
 def resolve_backend(backend: str = "auto") -> str:
@@ -118,6 +141,43 @@ def resolve_interpret(interpret: bool | str = "auto") -> bool:
     if interpret == "auto":
         return jax.default_backend() != "tpu"
     return bool(interpret)
+
+
+@contextlib.contextmanager
+def recording_impls() -> Iterator[Dict[str, Set[str]]]:
+    """Collect ``op -> {implementation}`` for every dispatched call made
+    inside the block."""
+    log: Dict[str, Set[str]] = {}
+    token = _IMPL_LOG.set(log)
+    try:
+        yield log
+    finally:
+        _IMPL_LOG.reset(token)
+
+
+def record_impl(op: str, impl: str) -> None:
+    """Add ``impl`` to the active log for ``op`` (no-op outside
+    :func:`recording_impls`).  For callers whose dispatch happens inside a
+    cached program, which does not re-trace on a later run."""
+    log = _IMPL_LOG.get()
+    if log is not None:
+        log.setdefault(op, set()).add(impl)
+
+
+def note_impl(impl: str) -> None:
+    """Called by a registered implementation that serves the current
+    dispatched call with something other than its default — e.g. the
+    oracle, past a VMEM budget.  Replaces the default record of the call."""
+    notes = _CALL_NOTES.get()
+    if notes is not None:
+        notes.append(impl)
+
+
+def default_impl(backend: str) -> str:
+    """The record of a call served the ordinary way by ``backend``."""
+    if backend == "reference":
+        return "reference"
+    return "pallas-interpret" if resolve_interpret("auto") else "pallas"
 
 
 def register_op(op: str, backend: str, fn: Callable) -> Callable:
@@ -168,6 +228,14 @@ def dispatch(op: str, backend: str = "auto") -> Callable:
     @functools.wraps(fn)
     def dispatched(*args, **kwargs):
         with span(f"op:{op}", kind="op", op=op, backend=b):
-            return fn(*args, **kwargs)
+            token = _CALL_NOTES.set([])
+            try:
+                out = fn(*args, **kwargs)
+            finally:
+                notes = _CALL_NOTES.get()
+                _CALL_NOTES.reset(token)
+            for impl in notes or [default_impl(b)]:
+                record_impl(op, impl)
+            return out
 
     return dispatched

@@ -49,9 +49,9 @@ from typing import Any, Dict, List, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
+from jax import shard_map
+from jax.sharding import AxisType, PartitionSpec as P
 
-from ..compat import shard_map
 from ..obs.trace import span
 from .components import _log2_ceil, expand_state_rows
 
@@ -90,14 +90,8 @@ def default_row_mesh():
     """1D ``("data",)`` mesh over all visible devices — the fallback mesh for
     ``distribution="shard_map"`` when the caller did not build one."""
     devs = jax.devices()
-    kwargs = {}
-    try:  # jax ≥ 0.5 wants explicit axis types
-        from jax.sharding import AxisType  # type: ignore[attr-defined]
-
-        kwargs["axis_types"] = (AxisType.Auto,)
-    except ImportError:  # pragma: no cover - version-dependent
-        pass
-    return jax.make_mesh((len(devs),), ("data",), devices=devs, **kwargs)
+    return jax.make_mesh((len(devs),), ("data",), devices=devs,
+                         axis_types=(AxisType.Auto,))
 
 
 def _ring_all_gather(x: jnp.ndarray, axis_name: str, n_shards: int):

@@ -153,9 +153,13 @@ def _take_first_pairs(xa, xb, xn, ya, yb):
     xn_b = xn[..., None]
     from_x = s < xn_b
     yidx = jnp.clip(s - xn_b, 0, NUM_POS_PAIRS - 1)
-    out_a = jnp.where(from_x, xa, jnp.take_along_axis(ya, yidx, axis=-1))
-    out_b = jnp.where(from_x, xb, jnp.take_along_axis(yb, yidx, axis=-1))
-    return out_a, out_b
+    # y's slot yidx by selects, not a gather: a gather on this 2-wide minor
+    # axis inside the segmented scans makes XLA's TPU compile blow up
+    ya_s, yb_s = ya[..., :1], yb[..., :1]
+    for j in range(1, NUM_POS_PAIRS):
+        ya_s = jnp.where(yidx == j, ya[..., j : j + 1], ya_s)
+        yb_s = jnp.where(yidx == j, yb[..., j : j + 1], yb_s)
+    return jnp.where(from_x, xa, ya_s), jnp.where(from_x, xb, yb_s)
 
 
 def _ov_add(x: Any, y: Any) -> Any:

@@ -150,19 +150,76 @@ def map_row_blocks(fn, inputs: Any, *, n_rows: int, row_chunk: int,
     return merged, aux
 
 
+def _split_planes(tree: Any, lead: int):
+    """Value leaves → flags-shaped planes: every leaf of shape
+    ``lead_shape + tail`` becomes ``prod(tail)`` arrays of ``lead_shape``.
+    Returns ``(planes, join)``; ``join(planes)`` rebuilds the pytree.
+
+    Narrow trailing axes (the overlap semiring's position pairs) under the
+    row sorts, gathers and scans of the candidate merge make XLA's TPU
+    compile time grow with the array size; 2-D planes do not."""
+    leaves, treedef = jax.tree.flatten(tree)
+    tails = [leaf.shape[lead:] for leaf in leaves]
+    sizes = [int(np.prod(t)) for t in tails]
+
+    def split(t):
+        out = []
+        for leaf, tail, size in zip(jax.tree.leaves(t), tails, sizes):
+            if not tail:
+                out.append(leaf)
+                continue
+            flat = leaf.reshape(leaf.shape[:lead] + (size,))
+            out += [flat[..., i] for i in range(size)]
+        return out
+
+    def join(planes):
+        out, i = [], 0
+        for tail, size in zip(tails, sizes):
+            if not tail:
+                out.append(planes[i])
+                i += 1
+                continue
+            stacked = jnp.stack(planes[i : i + size], axis=-1)
+            out.append(stacked.reshape(stacked.shape[:lead] + tail))
+            i += size
+        return jax.tree.unflatten(treedef, out)
+
+    return split(tree), split, join
+
+
 def _segmented_combine(flags: jnp.ndarray, vals: Any, add, axis: int = 0) -> Any:
     """Inclusive segmented scan along ``axis``: combine vals within runs
     (flags==True starts a new run).  Returns scanned vals (run-prefix sums
     under ``add``); the last element of each run holds the run total."""
+    planes, split, join = _split_planes(vals, flags.ndim)
 
     def op(x, y):
-        fx, vx = x
-        fy, vy = y
-        v = tree_where(fy, vy, add(vx, vy))
-        return (fx | fy, v)
+        fx, px = x
+        fy, py = y
+        vx, vy = join(px), join(py)
+        return (fx | fy, split(tree_where(fy, vy, add(vx, vy))))
 
-    _, out = jax.lax.associative_scan(op, (flags, vals), axis=axis)
-    return out
+    if flags.ndim > 1:
+        _, out = jax.lax.associative_scan(op, (flags, planes), axis=axis)
+        return join(out)
+    # One long axis (from_coo): log2(n) doubling steps in a loop, each
+    # combining with the element 2^i back.  associative_scan unrolls its
+    # levels over slices whose XLA TPU compile time grows with the length
+    # (minutes past a million entries).  Same result for an associative add.
+    n = flags.shape[0]
+    idx = jnp.arange(n)
+
+    def step(i, carry):
+        f, p = carry
+        back = idx >= (1 << i)
+        src = jnp.where(back, idx - (1 << i), 0)
+        fb, vb = op((f[src], [q[src] for q in p]), (f, p))
+        return (jnp.where(back, fb, f),
+                [jnp.where(back, b, q) for b, q in zip(vb, p)])
+
+    _, out = jax.lax.fori_loop(0, max(1, (n - 1).bit_length()), step,
+                               (flags, planes))
+    return join(out)
 
 
 def _rank_in_row_sorted(rows_sorted: jnp.ndarray, kept: jnp.ndarray) -> jnp.ndarray:
@@ -194,7 +251,15 @@ def from_coo(
     e = rows.shape[0]
     rkey = jnp.where(valid, rows, n_rows)
     ckey = jnp.where(valid, cols, n_cols)
-    order = jnp.lexsort((ckey, rkey))
+    if (n_rows + 1) * (n_cols + 1) <= 2**32:
+        # one uint32 (row, col) key: XLA's TPU compile time of a long 1-D
+        # sort grows with its key count (minutes for two past a million)
+        key = (rkey.astype(jnp.uint32) * np.uint32(n_cols + 1)
+               + ckey.astype(jnp.uint32))
+        _, order = jax.lax.sort((key, jnp.arange(e, dtype=jnp.int32)),
+                                num_keys=1, is_stable=True)
+    else:
+        order = jnp.lexsort((ckey, rkey))
     rs, cs = rkey[order], ckey[order]
     vs = jax.tree.map(lambda x: x[order], vals)
     valid_s = valid[order]
@@ -238,12 +303,8 @@ def merge_sorted_rows(
     key = jnp.where(cand_cols >= 0, cand_cols, big)
     order = jnp.argsort(key, axis=1)
     cs = jnp.take_along_axis(key, order, axis=1)
-    vs = jax.tree.map(
-        lambda v: jnp.take_along_axis(
-            v, order.reshape(order.shape + (1,) * (v.ndim - 2)), axis=1
-        ),
-        cand_vals,
-    )
+    planes, _, join = _split_planes(cand_vals, 2)
+    vs = join([jnp.take_along_axis(p, order, axis=1) for p in planes])
     valid = cs < big
     prev = jnp.concatenate([jnp.full((n, 1), -2, cs.dtype), cs[:, :-1]], axis=1)
     new_run = cs != prev
@@ -256,12 +317,8 @@ def merge_sorted_rows(
     order2 = jnp.argsort(ckey, axis=1)[:, :capacity]
     out_cols_raw = jnp.take_along_axis(ckey, order2, axis=1)
     out_cols = jnp.where(out_cols_raw < big, out_cols_raw.astype(jnp.int32), NO_COL)
-    out_vals = jax.tree.map(
-        lambda v: jnp.take_along_axis(
-            v, order2.reshape(order2.shape + (1,) * (v.ndim - 2)), axis=1
-        ),
-        scanned,
-    )
+    planes, _, join = _split_planes(scanned, 2)
+    out_vals = join([jnp.take_along_axis(p, order2, axis=1) for p in planes])
     out_vals = tree_where(out_cols >= 0, out_vals, semiring.zero((n, capacity)))
     overflow = jnp.sum(jnp.maximum(jnp.sum(kept, axis=1) - capacity, 0))
     return out_cols, out_vals, overflow

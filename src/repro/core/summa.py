@@ -56,12 +56,12 @@ from typing import Any, Dict, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax import shard_map
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
-from ..compat import pvary, shard_map
 from ..obs import schema, validated
 from ..obs.trace import span
-from .backend import dispatch, resolve_backend
+from .backend import dispatch, record_impl, resolve_backend
 from .semiring import INF, Semiring, minplus_orient_semiring as MPSR, tree_where
 from .spgemm import spgemm
 from .spmat import EllMatrix, NO_COL, from_coo, merge_sorted_rows, prune
@@ -297,14 +297,8 @@ def default_summa_mesh() -> Mesh:
     while d % pr:
         pr -= 1
     pc = d // pr
-    kwargs = {}
-    try:  # jax ≥ 0.5 wants explicit axis types
-        from jax.sharding import AxisType  # type: ignore[attr-defined]
-
-        kwargs["axis_types"] = (AxisType.Auto, AxisType.Auto)
-    except ImportError:  # pragma: no cover - version-dependent
-        pass
-    return jax.make_mesh((pr, pc), ("data", "model"), devices=devs, **kwargs)
+    return jax.make_mesh((pr, pc), ("data", "model"), devices=devs,
+                         axis_types=(AxisType.Auto, AxisType.Auto))
 
 
 def _slot_words(vals: Any) -> int:
@@ -429,7 +423,7 @@ def _ring_program(
 
         cur = (a_cols, a_vals, b_cols, b_vals)
         chunks_cols, chunks_vals = [], []
-        ovf = pvary(jnp.int32(0), both)
+        ovf = jax.lax.pcast(jnp.int32(0), both, to="varying")
         s = 0
         while s < pc:
             sc = min(g, pc - s)
@@ -594,25 +588,23 @@ def summa_ring(
         )
     cm = EllMatrix(cols=cc, vals=cv, n_cols=n_cols_out)
     fused = False
+    impl = "reference"
     if resolved == "pallas":
-        from ..kernels.spgemm.ops import fused_path_fits
+        from ..kernels.spgemm.ops import stage_impl
 
         sds = jax.ShapeDtypeStruct
         chunk = min(g, pc)
-        a_cols_l = sds((chunk, n_loc, ka), jnp.int32)
-        a_vals_l = jax.tree.map(
-            lambda v: sds((chunk, n_loc, ka) + v.shape[2:], v.dtype),
-            a.mat.vals,
-        )
-        b_cols_l = sds((chunk, nb_b, kb), jnp.int32)
-        b_vals_l = jax.tree.map(
-            lambda v: sds((chunk, nb_b, kb) + v.shape[2:], v.dtype),
-            b.mat.vals,
-        )
-        fused = fused_path_fits(
-            a_cols_l, a_vals_l, b_cols_l, b_vals_l,
+        fused, impl = stage_impl(
+            sds((chunk, n_loc, ka), jnp.int32),
+            jax.tree.map(lambda v: sds((chunk, n_loc, ka) + v.shape[2:],
+                                       v.dtype), a.mat.vals),
+            sds((chunk, nb_b, kb), jnp.int32),
+            jax.tree.map(lambda v: sds((chunk, nb_b, kb) + v.shape[2:],
+                                       v.dtype), b.mat.vals),
             capacity=out_block_capacity, semiring=semiring,
         )
+    # the cached ring program dispatches the op only when first traced
+    record_impl("spgemm_ring_stages", impl)
     from ..kernels.spgemm.ops import hbm_round_trips
 
     stats = validated({

@@ -4,7 +4,8 @@
 #   pileup/  — banded pileup accumulation + majority vote (consensus)
 #   cc/      — fused hook/shortcut connected-components rounds
 #   spgemm/  — fused ring-SUMMA local SpGEMM stage batches (overlap stage)
-# Validated on CPU via interpret=True against the pure-jnp oracles (ref.py).
+# Validated on CPU via interpret=True against the pure-jnp oracles (ref.py);
+# tests/test_chip_compile.py lowers each through the TPU compiler for v5e.
 # Importing this package registers every kernel (and its oracle) with the
 # backend dispatch layer in core/backend.py.
 from .cc import cc_labels_pallas, cc_labels_ref  # noqa: F401

@@ -7,8 +7,12 @@ the k grid dimension in-place (the revisited-output accumulation pattern).
 The orientation contraction (min over the middle strand c) rides along as two
 extra lanes.
 
-Block shapes default to (128, 128, 128) — 8×128-lane aligned; the innermost
-expansion buffer is (BM, BN, 2, 2, 2) f32 = 512 KB, well inside VMEM.
+Layout: the four orientation components travel as separate planes —
+``a`` as ``(4, M, K)``, ``b`` as ``(4, K, N)`` — so every operand the VPU
+touches is a plain 2-D (sublane, lane) tile.  Step k of the reduction needs
+column k of each ``a`` plane (dynamic lane rotate, then lane 0) and row k of
+each ``b`` plane (dynamic sublane slice); both lower to plain vector ops.
+Block shapes default to (128, 128, 128), tile-aligned.
 """
 
 from __future__ import annotations
@@ -18,6 +22,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ...core.backend import resolve_interpret
 
@@ -25,27 +30,31 @@ INF = float("inf")  # plain python float: Pallas kernels cannot capture traced c
 
 
 def _minplus_kernel(a_ref, b_ref, o_ref):
-    bm = a_ref.shape[0]
-    bk = a_ref.shape[1]
-    bn = b_ref.shape[1]
+    bm, bk = a_ref.shape[1], a_ref.shape[2]
+    bn = b_ref.shape[2]
 
     @pl.when(pl.program_id(2) == 0)
     def _init():
-        o_ref[...] = jnp.full((bm, bn, 4), INF, jnp.float32)
-
-    a = a_ref[...].reshape(bm, bk, 2, 2)
-    b = b_ref[...].reshape(bk, bn, 2, 2)
+        o_ref[...] = jnp.full(o_ref.shape, INF, jnp.float32)
 
     def body(k, acc):
-        ak = jax.lax.dynamic_slice_in_dim(a, k, 1, axis=1)[:, 0]  # (BM, 2, 2)
-        bk_ = jax.lax.dynamic_slice_in_dim(b, k, 1, axis=0)[0]  # (BN, 2, 2)
-        s = ak[:, None, :, :, None] + bk_[None, :, None, :, :]
-        # (BM, BN, x, c, y) -> min over c
-        return jnp.minimum(acc, jnp.min(s, axis=3))
+        # a[:, k, 2x+c] as (BM, 1) columns, b[k, :, 2c+y] as (1, BN) rows
+        acol = [
+            pltpu.roll(a_ref[p], (bk - k) % bk, 1)[:, 0:1] for p in range(4)
+        ]
+        brow = [b_ref[p, pl.ds(k, 1), :] for p in range(4)]
+        out = []
+        for x in range(2):
+            for y in range(2):
+                s0 = acol[2 * x] + brow[y]  # c = 0
+                s1 = acol[2 * x + 1] + brow[2 + y]  # c = 1
+                out.append(jnp.minimum(acc[2 * x + y], jnp.minimum(s0, s1)))
+        return tuple(out)
 
-    acc0 = jnp.full((bm, bn, 2, 2), INF, jnp.float32)
+    acc0 = tuple(jnp.full((bm, bn), INF, jnp.float32) for _ in range(4))
     acc = jax.lax.fori_loop(0, bk, body, acc0)
-    o_ref[...] = jnp.minimum(o_ref[...], acc.reshape(bm, bn, 4))
+    for p in range(4):
+        o_ref[p] = jnp.minimum(o_ref[p], acc[p])
 
 
 @functools.partial(
@@ -68,22 +77,20 @@ def minplus_pallas(
     n = b.shape[1]
     bm, bn, bk = min(block_m, m), min(block_n, n), min(block_k, k)
     pm, pn, pk = -(-m // bm) * bm, -(-n // bn) * bn, -(-k // bk) * bk
-    if (pm, pk) != (m, k):
-        a = jnp.pad(a, ((0, pm - m), (0, pk - k), (0, 0)),
-                    constant_values=jnp.inf)
-    if (pk, pn) != (k, n):
-        b = jnp.pad(b, ((0, pk - k), (0, pn - n), (0, 0)),
-                    constant_values=jnp.inf)
+    ap = jnp.pad(jnp.moveaxis(a.astype(jnp.float32), 2, 0),
+                 ((0, 0), (0, pm - m), (0, pk - k)), constant_values=INF)
+    bp = jnp.pad(jnp.moveaxis(b.astype(jnp.float32), 2, 0),
+                 ((0, 0), (0, pk - k), (0, pn - n)), constant_values=INF)
     grid = (pm // bm, pn // bn, pk // bk)
     out = pl.pallas_call(
         _minplus_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bm, bk, 4), lambda i, j, kk: (i, kk, 0)),
-            pl.BlockSpec((bk, bn, 4), lambda i, j, kk: (kk, j, 0)),
+            pl.BlockSpec((4, bm, bk), lambda i, j, kk: (0, i, kk)),
+            pl.BlockSpec((4, bk, bn), lambda i, j, kk: (0, kk, j)),
         ],
-        out_specs=pl.BlockSpec((bm, bn, 4), lambda i, j, kk: (i, j, 0)),
-        out_shape=jax.ShapeDtypeStruct((pm, pn, 4), jnp.float32),
+        out_specs=pl.BlockSpec((4, bm, bn), lambda i, j, kk: (0, i, j)),
+        out_shape=jax.ShapeDtypeStruct((4, pm, pn), jnp.float32),
         interpret=interpret,
-    )(a.astype(jnp.float32), b.astype(jnp.float32))
-    return out[:m, :n]
+    )(ap, bp)
+    return jnp.moveaxis(out[:, :m, :n], 0, 2)

@@ -21,7 +21,12 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from ...core.backend import register_op
+from ...core.backend import (
+    default_impl,
+    note_impl,
+    register_op,
+    resolve_interpret,
+)
 from ...core.semiring import Semiring
 from ...obs.trace import span
 from .ref import spgemm_ring_stages_ref
@@ -32,6 +37,33 @@ from .spgemm import spgemm_ring_stages_pallas as _pallas_raw
 # pallas backend falls back to the oracle — documented behaviour,
 # bit-identical either way.
 VMEM_BUDGET_BYTES = 8 << 20
+
+# The fused kernel runs core.spmat.merge_sorted_rows (a sort) and multi-vreg
+# gathers in its body; the TPU compiler lowers neither, so compiled on a TPU
+# the op runs its oracle — an explicit, recorded choice (op_impls,
+# summa_backend), never a silent switch.
+NO_TPU_LOWERING = "reference (no TPU lowering: in-kernel sort)"
+
+
+def kernel_runs(interpret: bool | str = "auto") -> bool:
+    """True iff the fused kernel can run on this platform: in interpret mode
+    only (see ``NO_TPU_LOWERING``)."""
+    return resolve_interpret(interpret)
+
+
+def stage_impl(
+    a_cols, a_vals, b_cols, b_vals, *, capacity: int, semiring: Semiring,
+    interpret: bool | str = "auto",
+) -> tuple[bool, str]:
+    """``(fused, impl)`` for one stage batch of the pallas backend: whether
+    the fused kernel runs, and the implementation recorded for the call.
+    Operands may be arrays or ``ShapeDtypeStruct``s (only shapes are read)."""
+    if not kernel_runs(interpret):
+        return False, NO_TPU_LOWERING
+    if not fused_path_fits(a_cols, a_vals, b_cols, b_vals,
+                           capacity=capacity, semiring=semiring):
+        return False, "reference (VMEM budget)"
+    return True, default_impl("pallas")
 
 
 def _words_per_slot(vals) -> int:
@@ -107,12 +139,14 @@ def spgemm_ring_stages_pallas(
     """Pallas backend of the ``spgemm_ring_stages`` op: the fused kernel with
     the VMEM-budget fallback.  Bit-identical stage buffers and overflow
     counts to :func:`~repro.kernels.spgemm.ref.spgemm_ring_stages_ref`."""
-    fused = fused_path_fits(a_cols, a_vals, b_cols, b_vals,
-                            capacity=capacity, semiring=semiring)
+    fused, impl = stage_impl(a_cols, a_vals, b_cols, b_vals,
+                             capacity=capacity, semiring=semiring,
+                             interpret=interpret)
     with span("kernel_launch", kind="kernel", kernel="spgemm_ring_stages",
               fused=fused, stages=int(a_cols.shape[0]),
               rows=int(a_cols.shape[1])):
         if not fused:
+            note_impl(impl)
             return spgemm_ring_stages_ref(
                 offsets, a_cols, a_vals, b_cols, b_vals, semiring=semiring,
                 capacity=capacity, n_cols_out=n_cols_out,

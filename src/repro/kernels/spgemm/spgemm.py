@@ -1,5 +1,9 @@
 """Pallas TPU kernel: fused ring-SUMMA local SpGEMM stages.
 
+Interpret mode only: the TPU compiler lowers neither the in-kernel sort nor
+the multi-vreg gathers, so compiled on a TPU the op runs its oracle
+(``ops.NO_TPU_LOWERING``).
+
 Hardware adaptation (DESIGN.md §2.11): the jnp oracle runs one
 gather → semiring-⊗ → sort-by-column → segmented-⊕ → compact pipeline per
 ring stage, paying a full HBM round trip per stage for the stage's candidate
@@ -74,8 +78,7 @@ def _spgemm_stages_kernel(
 ):
     """Kernel body.  ``refs`` = (off, a_cols, *a_leaves, b_cols, *b_leaves)
     inputs followed by (st_cols, *st_leaves, ovf) outputs, every array
-    flattened to one ``(1, numel)`` row (the shared flat-row BlockSpec idiom
-    of the cc/pileup kernels)."""
+    flattened to one ``(1, numel)`` row."""
     na, nbl = len(a_tails), len(b_tails)
     it = iter(refs)
     off_ref = next(it)

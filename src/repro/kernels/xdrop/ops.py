@@ -18,12 +18,12 @@ def xdrop_extend_batch(a, base_a, step_a, len_a, b, base_b, step_b, len_b,
                        interpret: bool | str = "auto", **kw):
     """Batched single-direction x-drop extension on the Pallas kernel.
 
-    ``pairs_per_block=None`` picks the block size for the platform: a small
-    sublane-friendly block when compiled, the whole batch in interpret mode
-    (the grid loop is unrolled at trace time there, so fewer blocks = smaller
+    ``pairs_per_block=None`` picks the block size for the platform: one
+    128-lane block when compiled, the whole batch in interpret mode (the
+    grid loop is unrolled at trace time there, so fewer blocks = smaller
     HLO and one kernel instantiation)."""
     if pairs_per_block is None:
-        pairs_per_block = int(a.shape[0]) if resolve_interpret(interpret) else 8
+        pairs_per_block = int(a.shape[0]) if resolve_interpret(interpret) else 128
     with span("kernel_launch", kind="kernel", kernel="xdrop_extend",
               pairs=int(a.shape[0]), pairs_per_block=pairs_per_block):
         return xdrop_pallas(

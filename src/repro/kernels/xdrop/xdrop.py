@@ -1,12 +1,19 @@
 """Pallas TPU kernel: banded x-drop seed-extension wavefront.
 
 Hardware adaptation (DESIGN.md §2): SeqAn's SSE anti-diagonal vectorization
-becomes a (PAIRS_PER_BLOCK, BAND) wavefront living in VMEM/VREGs — the band
-fills the 128-wide lane dimension and a block of pairs fills the sublane
-dimension, so every VPU op advances BAND cells of PB alignments at once.
-The DP state is two wavefronts + running best (score, ai, bj); sequences are
-staged into VMEM by the BlockSpec.  Fixed trip count (max_steps) with
+becomes a (BAND, PAIRS) wavefront living in VMEM/VREGs — the band runs down
+the sublanes and a block of 128·k pairs fills the lanes, so every VPU op
+advances every band cell of 128 alignments at once.  The DP state is two
+wavefronts + running best (score, ai, bj); fixed trip count (max_steps) with
 x-drop retirement masking — identical semantics to the oracle.
+
+Sequence layout.  At step ``s`` band row ``r`` (diagonal ``d = r − c``)
+reads ``a[(s + d) // 2]`` and ``b[(s − d) // 2]``.  The wrapper stages each
+pair's extension text *doubled* (every base twice, so ``(s + d) // 2``
+becomes the linear row ``s + r``) and already walked in the pair's direction
+— ``b`` additionally reversed — so the kernel's per-step fetch is one
+contiguous dynamic sublane window of each staged text.  No in-kernel gather,
+no lane shuffle: the TPU compiler lowers it as plain vector loads.
 """
 
 from __future__ import annotations
@@ -16,83 +23,84 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ...core.backend import resolve_interpret
 
 NEG = -(10**9) // 2  # plain int: Pallas kernels cannot capture traced consts
+LANES = 128
+
+
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
 
 
 def _xdrop_kernel(
-    a_ref, ba_ref, sa_ref, la_ref, b_ref, bb_ref, sb_ref, lb_ref,
-    score_ref, ai_ref, bj_ref,
-    *, band: int, max_steps: int, xdrop: int, match: int, mismatch: int,
-    gap: int,
+    la_ref, lb_ref, a2_ref, b2_ref, score_ref, ai_ref, bj_ref, h_ref,
+    *, band: int, s_max: int, max_steps: int, xdrop: int, match: int,
+    mismatch: int, gap: int,
 ):
-    pb = a_ref.shape[0]
-    w = band
-    c = w // 2
-    offs = jnp.arange(w) - c  # (W,)
-    a = a_ref[...].astype(jnp.int32)  # (PB, LA)
-    b = b_ref[...].astype(jnp.int32)
-    ba = ba_ref[...].astype(jnp.int32)[:, None]  # (PB, 1)
-    sa = sa_ref[...].astype(jnp.int32)[:, None]
-    la = la_ref[...].astype(jnp.int32)[:, None]
-    bb = bb_ref[...].astype(jnp.int32)[:, None]
-    sb = sb_ref[...].astype(jnp.int32)[:, None]
-    lb = lb_ref[...].astype(jnp.int32)[:, None]
-    lmax_a = a.shape[1]
-    lmax_b = b.shape[1]
-
-    def fetch(seq, base, step, t, lim, lmax):
-        idx = base + step * t  # (PB, W)
-        safe = jnp.clip(idx, 0, lmax - 1)
-        v = jnp.take_along_axis(seq, safe, axis=1)
-        return v, (t >= 0) & (t < lim)
+    wp, pb = h_ref.shape[1], h_ref.shape[2]
+    c = band // 2
+    la = la_ref[...]  # (1, PB)
+    lb = lb_ref[...]
+    # the two live wavefronts sit in VMEM scratch: step s reads H[s−2] from
+    # slot s % 2 and H[s−1] from the other slot, then overwrites H[s−2]
+    r0 = jax.lax.broadcasted_iota(jnp.int32, (wp, pb), 0)
+    h_ref[0] = jnp.where(r0 == c, 0, NEG).astype(jnp.int32)  # origin, s−2
+    h_ref[1] = jnp.full((wp, pb), NEG, jnp.int32)
 
     def step_fn(s, carry):
-        h1, h2, best, bi, bj, alive = carry
-        i = (s + offs[None, :]) // 2  # (1+PB broadcast, W)
-        j = (s - offs[None, :]) // 2
-        parity = ((s + offs[None, :]) % 2) == 0
-        av, va = fetch(a, ba, sa, i, la, lmax_a)
-        bv, vb = fetch(b, bb, sb, j, lb, lmax_b)
-        valid = parity & va & vb & (i >= 0) & (j >= 0)
+        best, bi, bj, alive = carry
+        h2 = h_ref[s % 2]
+        h1 = h_ref[(s + 1) % 2]
+        r = jax.lax.broadcasted_iota(jnp.int32, (wp, pb), 0)
+        sf = jnp.minimum(s, s_max)  # past s_max no cell is valid
+        av = a2_ref[pl.ds(sf, wp), :]
+        bv = b2_ref[pl.ds(s_max - sf, wp), :]
+        t = s + r - c  # = 2i, or 2i + 1 off-parity
+        i = t >> 1
+        j = (s - r + c) >> 1
+        valid = (
+            ((t & 1) == 0) & (r < band) & (i >= 0) & (i < la)
+            & (j >= 0) & (j < lb)
+        )
         sub = jnp.where(av == bv, match, mismatch)
         diag = h2 + sub
-        up = jnp.concatenate(
-            [jnp.full((pb, 1), NEG), h1[:, :-1]], axis=1
-        ) + gap
-        left = jnp.concatenate(
-            [h1[:, 1:], jnp.full((pb, 1), NEG)], axis=1
-        ) + gap
+        # row shifts along the band; the wrapped-in row is a padding row
+        # (always NEG), exactly the oracle's NEG fill at the band edges
+        up = pltpu.roll(h1, 1, 0) + gap
+        left = pltpu.roll(h1, wp - 1, 0) + gap
         h = jnp.maximum(diag, jnp.maximum(up, left))
         h = jnp.where(valid, h, NEG)
-        h = jnp.where(h < best[:, None] - xdrop, NEG, h)
-        h = jnp.where(alive[:, None], h, NEG)
-        m = jnp.max(h, axis=1)
-        am = jnp.argmax(h, axis=1)
+        h = jnp.where(h < best - xdrop, NEG, h)
+        h = jnp.where(alive > 0, h, NEG)
+        h_ref[s % 2] = h
+        m = jnp.max(h, axis=0, keepdims=True)  # (1, PB)
+        # first row holding the max == the oracle's argmax tie-break
+        am = jnp.min(jnp.where(h == m, r, wp), axis=0, keepdims=True)
         improved = m > best
         best2 = jnp.where(improved, m, best)
-        ii = jnp.take_along_axis(i, am[:, None], axis=1)[:, 0]
-        jj = jnp.take_along_axis(j, am[:, None], axis=1)[:, 0]
-        bi2 = jnp.where(improved, ii + 1, bi)
-        bj2 = jnp.where(improved, jj + 1, bj)
-        alive2 = jnp.any(h > NEG, axis=1) & (s + 1 < la[:, 0] + lb[:, 0] - 1)
-        return (h, h1, best2, bi2, bj2, alive2)
+        bi2 = jnp.where(improved, ((s + am - c) >> 1) + 1, bi)
+        bj2 = jnp.where(improved, ((s - am + c) >> 1) + 1, bj)
+        alive2 = ((m > NEG) & (s + 1 < la + lb - 1)).astype(jnp.int32)
+        return best2, bi2, bj2, alive2
 
-    h1 = jnp.full((pb, w), NEG)
-    h2 = jnp.where((offs == 0)[None, :], 0, NEG) | jnp.zeros((pb, w), jnp.int32)
-    init = (
-        h1, h2,
-        jnp.zeros((pb,), jnp.int32),
-        jnp.zeros((pb,), jnp.int32),
-        jnp.zeros((pb,), jnp.int32),
-        jnp.ones((pb,), bool),
+    zero = la * 0
+    best, bi, bj, _ = jax.lax.fori_loop(
+        0, max_steps, step_fn, (zero, zero, zero, zero + 1)
     )
-    h1, h2, best, bi, bj, alive = jax.lax.fori_loop(0, max_steps, step_fn, init)
     score_ref[...] = best
     ai_ref[...] = bi
     bj_ref[...] = bj
+
+
+def _stage_text(seq, base, step, t):
+    """``seq[p, base_p + step_p·t]`` for a (rows, 1) walk index ``t``, laid
+    out (rows, pairs): pairs along lanes, walk position down the sublanes."""
+    idx = base[None, :] + step[None, :] * t
+    idx = jnp.clip(idx, 0, seq.shape[1] - 1)
+    return jnp.take_along_axis(seq.T, idx, axis=0).astype(jnp.int32)
 
 
 @functools.partial(
@@ -105,48 +113,59 @@ def _xdrop_kernel(
 def xdrop_pallas(
     a, base_a, step_a, len_a, b, base_b, step_b, len_b, *,
     band: int = 33, max_steps: int = 256, xdrop: int = 15, match: int = 1,
-    mismatch: int = -1, gap: int = -1, pairs_per_block: int = 8,
+    mismatch: int = -1, gap: int = -1, pairs_per_block: int = LANES,
     interpret: bool | str = "auto",
 ):
+    """Batched x-drop extension; ``pairs_per_block`` is rounded up to a
+    multiple of the 128-lane width (pairs ride the lanes)."""
     interpret = resolve_interpret(interpret)
     e, lmax_a = a.shape
     lmax_b = b.shape[1]
-    pb = min(pairs_per_block, e)
-    pe = -(-e // pb) * pb
-    pad = pe - e
+    pb = _round_up(max(1, min(pairs_per_block, e)), LANES)
+    pe = _round_up(e, pb)
+    c = band // 2
+    wp = _round_up(band + 1, 8)  # ≥ 1 padding row absorbs the roll wrap
+    # last fetch start: no cell is valid once s ≥ lmax_a + lmax_b − 1
+    s_max = min(max_steps, lmax_a + lmax_b)
+    rows = _round_up(s_max + wp, 8)
+    s_max = rows - wp
 
-    def p1(x):
-        return jnp.pad(x, ((0, pad),))
+    def lanes(x):
+        return jnp.pad(x.astype(jnp.int32), (0, pe - e)).reshape(1, pe)
 
-    def p2(x, l):
-        return jnp.pad(x, ((0, pad), (0, 0)))
+    u = jnp.arange(rows, dtype=jnp.int32)[:, None]
+    # a2[u] = a_text[(u − c) // 2]; b2[v] = b_text[(s_max + c − v) // 2]
+    a2 = _stage_text(a, base_a.astype(jnp.int32), step_a.astype(jnp.int32),
+                     (u - c) >> 1)
+    b2 = _stage_text(b, base_b.astype(jnp.int32), step_b.astype(jnp.int32),
+                     (s_max + c - u) >> 1)
+    a2 = jnp.pad(a2, ((0, 0), (0, pe - e)))
+    b2 = jnp.pad(b2, ((0, 0), (0, pe - e)))
 
-    a = p2(a, lmax_a)
-    b = p2(b, lmax_b)
-    base_a, step_a, len_a = p1(base_a), p1(step_a), p1(len_a)
-    base_b, step_b, len_b = p1(base_b), p1(step_b), p1(len_b)
-    grid = (pe // pb,)
+    # inside a shard_map the outputs vary over whatever mesh axes the
+    # inputs do (the distributed alignment region, core/align_dist.py)
+    vma = frozenset().union(*(
+        jax.typeof(x).vma for x in (a, base_a, step_a, len_a, b, base_b,
+                                    step_b, len_b)
+    ))
     kernel = functools.partial(
-        _xdrop_kernel, band=band, max_steps=max_steps, xdrop=xdrop,
-        match=match, mismatch=mismatch, gap=gap,
+        _xdrop_kernel, band=band, s_max=s_max, max_steps=max_steps,
+        xdrop=xdrop, match=match, mismatch=mismatch, gap=gap,
     )
-    seq_spec_a = pl.BlockSpec((pb, lmax_a), lambda i: (i, 0))
-    seq_spec_b = pl.BlockSpec((pb, lmax_b), lambda i: (i, 0))
-    scal = pl.BlockSpec((pb,), lambda i: (i,))
+    row = pl.BlockSpec((1, pb), lambda i: (0, i))
+    seq = pl.BlockSpec((rows, pb), lambda i: (0, i))
+    # two staged texts, double-buffered, plus headroom for the wavefront
+    vmem = 4 * rows * pb * 4 + (4 << 20)
     score, ai, bj = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[seq_spec_a, scal, scal, scal, seq_spec_b, scal, scal, scal],
-        out_specs=[scal, scal, scal],
-        out_shape=[
-            jax.ShapeDtypeStruct((pe,), jnp.int32),
-            jax.ShapeDtypeStruct((pe,), jnp.int32),
-            jax.ShapeDtypeStruct((pe,), jnp.int32),
-        ],
+        grid=(pe // pb,),
+        in_specs=[row, row, seq, seq],
+        out_specs=[row, row, row],
+        out_shape=[jax.ShapeDtypeStruct((1, pe), jnp.int32, vma=vma)] * 3,
+        scratch_shapes=[pltpu.VMEM((2, wp, pb), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=max(vmem, 16 << 20)
+        ),
         interpret=interpret,
-    )(
-        a, base_a.astype(jnp.int32), step_a.astype(jnp.int32),
-        len_a.astype(jnp.int32), b, base_b.astype(jnp.int32),
-        step_b.astype(jnp.int32), len_b.astype(jnp.int32),
-    )
-    return score[:e], ai[:e], bj[:e]
+    )(lanes(len_a), lanes(len_b), a2, b2)
+    return score[0, :e], ai[0, :e], bj[0, :e]

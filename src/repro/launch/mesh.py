@@ -8,19 +8,7 @@ XLA_FLAGS before any jax import to obtain 512 host devices.
 from __future__ import annotations
 
 import jax
-
-try:  # jax ≥ 0.5 exposes explicit axis types; older versions have neither
-    from jax.sharding import AxisType  # type: ignore[attr-defined]
-except ImportError:  # pragma: no cover - version-dependent
-    AxisType = None
-
-
-def _axis_type_kwargs(n_axes: int) -> dict:
-    """``axis_types=(Auto, ...)`` where supported; {} on older jax (whose
-    ``jax.make_mesh`` predates the parameter and defaults to auto anyway)."""
-    if AxisType is None:
-        return {}
-    return {"axis_types": (AxisType.Auto,) * n_axes}
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -33,7 +21,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     # 256 of them
     devs = jax.devices()[:n]
     return jax.make_mesh(
-        shape, axes, devices=devs, **_axis_type_kwargs(len(axes))
+        shape, axes, devices=devs, axis_types=(AxisType.Auto,) * len(axes)
     )
 
 
@@ -43,7 +31,8 @@ def make_test_mesh(shape=(2, 2), axes=("data", "model")):
     for s_ in shape:
         n *= s_
     return jax.make_mesh(
-        shape, axes, devices=jax.devices()[:n], **_axis_type_kwargs(len(axes))
+        shape, axes, devices=jax.devices()[:n],
+        axis_types=(AxisType.Auto,) * len(axes),
     )
 
 

@@ -24,7 +24,7 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
-from ..compat import shard_map
+from jax import shard_map
 from .attention import (
     cache_update,
     decode_attention,

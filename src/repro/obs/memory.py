@@ -61,22 +61,25 @@ class MemorySample:
 
 
 def _device_stats() -> Optional[MemorySample]:
-    """Allocator stats maxed over devices, or None when unavailable.
+    """Allocator stats maxed over devices, or None where the backend keeps
+    none (``memory_stats()`` returns None on the CPU backend).
 
-    ``memory_stats()`` returns None on the CPU backend and may raise on
-    exotic platforms; both cases route to the live-buffer fallback."""
+    On a TPU the allocator always reports, so a failure there raises: a
+    live-buffer number must never stand in for the device watermark."""
     in_use = peak = None
-    try:
-        for dev in jax.devices():
-            stats = dev.memory_stats()
-            if not stats:
-                return None
-            b = int(stats.get("bytes_in_use", 0))
-            p = int(stats.get("peak_bytes_in_use", b))
-            in_use = b if in_use is None else max(in_use, b)
-            peak = p if peak is None else max(peak, p)
-    except Exception:  # pragma: no cover - platform-dependent
-        return None
+    for dev in jax.devices():
+        stats = dev.memory_stats()
+        if not stats:
+            if dev.platform == "tpu":
+                raise RuntimeError(
+                    f"{dev} returned no memory_stats(); the TPU allocator "
+                    "watermark is unavailable"
+                )
+            return None
+        b = int(stats.get("bytes_in_use", 0))
+        p = int(stats.get("peak_bytes_in_use", b))
+        in_use = b if in_use is None else max(in_use, b)
+        peak = p if peak is None else max(peak, p)
     if in_use is None:  # pragma: no cover - no devices
         return None
     return MemorySample(in_use, max(peak, in_use), "device_stats")

@@ -66,6 +66,9 @@ _SPECS: Tuple[MetricSpec, ...] = (
     # --- pipeline-wide ---
     _c("n_reads", "reads", "input reads"),
     _l("backend", "resolved kernel backend (reference|pallas)"),
+    MetricSpec("op_impls", "group", "label",
+               "dispatched op -> implementations that ran "
+               "(reference|pallas|pallas-interpret|reference (<reason>))"),
     # --- device-memory watermark (obs/memory.py) ---
     _c("peak_hbm_bytes", "bytes",
        "device-memory high-water mark over the assemble window "
@@ -98,6 +101,8 @@ _SPECS: Tuple[MetricSpec, ...] = (
        "(fused: ceil(pc/stages_per_call))"),
     _c("spgemm_hbm_round_trips_reference", "trips",
        "HBM round trips of the per-stage reference path (= pc)"),
+    _c("spgemm_row_chunk", "rows",
+       "rows per block of the gspmd overlap SpGEMM (n_reads = one block)"),
     _c("overflow_C", "entries", "candidate entries dropped by K_C capacity"),
     _c("nnz_C", "entries", "nonzeros of the candidate matrix C = A*At"),
     _g("c_density", "entries/read", "nnz_C per read"),

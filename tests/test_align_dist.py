@@ -154,6 +154,28 @@ def test_align_bucket_single_device_matches_batch_extend():
     assert stats["exchange_rounds_align"] == 0
 
 
+def test_align_bucket_single_device_pallas_matches_reference():
+    """The shard_map region runs the Pallas x-drop kernel too (its varying
+    manual axes type-check), bit-identical to the reference extension."""
+    from repro.core.align_dist import align_bucket_shard_map
+
+    a, la, b, lb, pa, pb = _pair_batch(12, 0.1, e=6)
+    codes = np.concatenate([a, b], 0)
+    cand = {
+        "i": np.arange(6), "j": 6 + np.arange(6), "li": la, "lj": lb,
+        "pa": pa, "pb": pb, "strand": np.zeros(6, np.int32),
+    }
+    cand = {key: jnp.asarray(v, jnp.int32) for key, v in cand.items()}
+    res, _ = align_bucket_shard_map(
+        jnp.asarray(codes), cand, k=_K, backend="pallas", band=17,
+        max_steps=128,
+    )
+    exp = _extend(a, la, b, lb, pa, pb)
+    for name, x, y in zip(exp._fields, exp, res):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y),
+                                      err_msg=name)
+
+
 # ---------------------------------------------------------------------------
 # multi-device parity + exact exchange accounting (subprocess)
 # ---------------------------------------------------------------------------
@@ -317,7 +339,7 @@ _MEM_KEYS = ("peak_hbm_bytes", "hbm_bytes_in_use", "hbm_source")
 # than the result (the host contig walk reports cc_iterations=0; the device
 # pointer-doubling path reports the round count)
 _PATH_KEYS = ("backend", "summa_backend", "tr_backend", "distribution",
-              "cc_iterations")
+              "cc_iterations", "op_impls")
 
 
 def _stats_sans(stats, drop):

@@ -11,8 +11,12 @@ import pytest
 from repro.assembly.pipeline import PipelineConfig, assemble
 from repro.assembly.simulate import simulate_genome, simulate_reads
 from repro.core.backend import (
+    _REGISTRY,
     available_backends,
     dispatch,
+    note_impl,
+    recording_impls,
+    register_op,
     resolve_backend,
     resolve_interpret,
 )
@@ -122,3 +126,25 @@ def test_tr_backend_parity_on_random_graph():
     assert ell_equal(s_ref, s_pal)
     assert int(st_ref.iterations) == int(st_pal.iterations)
     assert int(st_ref.nnz_final) == int(st_pal.nnz_final)
+
+
+def test_dispatch_records_the_implementation_that_ran():
+    """Every dispatched call lands in ``recording_impls``: the backend's
+    ordinary record, or what the implementation noted instead (an oracle
+    fallback must never pass for the kernel)."""
+
+    def fallback():
+        note_impl("reference (budget)")
+        return 2
+
+    register_op("_probe", "reference", lambda: 1)
+    register_op("_probe", "pallas", fallback)
+    try:
+        with recording_impls() as log:
+            assert dispatch("_probe", "reference")() == 1
+            assert dispatch("_probe", "pallas")() == 2
+        dispatch("_probe", "reference")()  # outside: not recorded
+    finally:
+        _REGISTRY.pop(("_probe", "reference"))
+        _REGISTRY.pop(("_probe", "pallas"))
+    assert log == {"_probe": {"reference", "reference (budget)"}}

@@ -268,6 +268,25 @@ def test_watermark_measures_allocations():
     del x
 
 
+def test_memory_sample_on_tpu_without_stats_raises(monkeypatch):
+    """On a TPU the allocator watermark is the only acceptable source: a
+    device that reports no ``memory_stats()`` raises instead of being
+    relabelled with live-buffer bytes."""
+    import jax
+
+    from repro.obs import memory
+
+    class _Dev:
+        platform = "tpu"
+
+        def memory_stats(self):
+            return None
+
+    monkeypatch.setattr(jax, "devices", lambda: [_Dev()])
+    with pytest.raises(RuntimeError, match="memory_stats"):
+        memory.sample()
+
+
 def test_watermark_outer_absorbs_nested_samples():
     """An inner window's sample points fold into every open outer window,
     so an allocation freed before the outer exit still shows in its peak."""

@@ -8,6 +8,7 @@ from collections import Counter
 
 import numpy as np
 import jax.numpy as jnp
+import pytest
 from _hypothesis_compat import given, settings, st
 
 from repro.core.semiring import count_semiring as CS
@@ -40,6 +41,25 @@ def test_from_coo_matches_dense_accumulation(triples):
         valid = cols_np[r][cols_np[r] >= 0]
         assert (np.diff(valid) > 0).all()
         assert (cols_np[r][len(valid):] == -1).all()
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_from_coo_one_key_and_two_key_sorts_agree(seed):
+    """Shapes with (n_rows+1)·(n_cols+1) ≤ 2^32 sort one uint32 (row, col)
+    key, larger ones two keys; the same triplets give the same rows."""
+    rng = np.random.default_rng(seed)
+    e = 200
+    rows = jnp.asarray(rng.integers(0, 12, e), jnp.int32)
+    cols = jnp.asarray(rng.integers(0, 40, e), jnp.int32)
+    vals = jnp.asarray(rng.integers(1, 5, e), jnp.int32)
+    ok = jnp.asarray(rng.random(e) < 0.8)
+    one, ovf1 = from_coo(rows, cols, vals, ok, n_rows=12, n_cols=40,
+                         capacity=6, semiring=CS)
+    two, ovf2 = from_coo(rows, cols, vals, ok, n_rows=12, n_cols=1 << 30,
+                         capacity=6, semiring=CS)
+    np.testing.assert_array_equal(np.asarray(one.cols), np.asarray(two.cols))
+    np.testing.assert_array_equal(np.asarray(one.vals), np.asarray(two.vals))
+    assert int(ovf1) == int(ovf2) > 0
 
 
 def test_overflow_counted_not_dropped_silently():
