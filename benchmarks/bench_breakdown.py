@@ -29,7 +29,7 @@ def render_span_tree(tracer, max_depth: int = 4) -> str:
     parent, depth-capped at ``max_depth``.  This is the human-readable twin
     of the Chrome trace export (``obs.write_chrome_trace``): the breakdown
     benchmark prints it so a ``--trace`` run shows the stage → shard_map
-    phase → kernel-launch nesting without opening Perfetto."""
+    phase → op nesting without opening Perfetto."""
     lines = []
 
     def _fmt(sp, depth):

@@ -10,7 +10,7 @@ individually jitted, and the overlap SpGEMM + transitive reduction can run
 either locally or 2D-distributed over a mesh (SUMMA).  Per-stage wall-clock is
 collected for the Fig. 5–8 style breakdown benchmark; with
 ``PipelineConfig.trace`` the same stage boundaries open :mod:`repro.obs`
-spans, nesting the shard_map phase and kernel-launch spans the sub-stages
+spans, nesting the shard_map phase and dispatched-op spans the sub-stages
 emit, and the resulting span tree is exportable as a Chrome trace
 (``repro.obs.write_chrome_trace``).
 """
@@ -39,7 +39,15 @@ from ..core.transitive_reduction import (
     transitive_reduction,
     transitive_reduction_fused,
 )
-from ..obs import Metrics, Tracer, span, tracing, watermark
+from ..obs import (
+    Metrics,
+    Tracer,
+    counting_readbacks,
+    readback,
+    span,
+    tracing,
+    watermark,
+)
 from . import alignment as al
 from .consensus import polish_contig_set
 from .contig_gen import generate_contigs
@@ -101,8 +109,8 @@ class PipelineConfig:
     # ring-SUMMA stages fused per spgemm_ring_stages call (the fused Pallas
     # kernel's HBM round trips = ceil(√P / this))
     summa_stages_per_call: int = 4
-    # collect a hierarchical span trace (stage → shard_map phase → kernel
-    # launch) on AssemblyResult.trace; spans also forward to
+    # collect a hierarchical span trace (stage → shard_map phase → op) on
+    # AssemblyResult.trace; spans and host readbacks also forward to
     # jax.profiler.TraceAnnotation so device profiles carry the same names
     trace: bool = False
 
@@ -126,6 +134,29 @@ class AssemblyResult:
         return self.consensus.to_contigs() if self.consensus else self.contigs
 
 
+# Key the persistent compile cache on op metadata.  By default JAX strips
+# metadata from the key, so it reuses an executable cached from the same
+# program under other op names (another version of this code, before a
+# named scope was added), and a profile then reads that version's names.
+# Locations keep one frame, so a key does not change with the caller's
+# line: the alignment's per-job compile still finds its cached program.
+# (Turning full tracebacks off instead drops most ops' names.)
+_CACHE_KEY_FLAGS = {"jax_compilation_cache_include_metadata_in_key": True,
+                    "jax_traceback_in_locations_limit": 1}
+
+
+@contextlib.contextmanager
+def _op_names_in_cache_key():
+    was = {flag: getattr(jax.config, flag) for flag in _CACHE_KEY_FLAGS}
+    for flag, value in _CACHE_KEY_FLAGS.items():
+        jax.config.update(flag, value)
+    try:
+        yield
+    finally:
+        for flag, value in was.items():
+            jax.config.update(flag, value)
+
+
 @contextlib.contextmanager
 def _tic(timings, key):
     """Stage timing as a thin wrapper over :func:`repro.obs.span` — the one
@@ -143,7 +174,8 @@ def assemble(codes, lengths, cfg: PipelineConfig = PipelineConfig()) -> Assembly
     # so every AssemblyResult.stats carries the peak_hbm_bytes family —
     # HBM capacity is the genome-size ceiling, and the watermark is what the
     # bench trajectory and the regression gate track
-    with watermark() as wm, recording_impls() as impls:
+    with watermark() as wm, recording_impls() as impls, \
+            counting_readbacks() as reads, _op_names_in_cache_key():
         tracer = Tracer(annotate=True) if cfg.trace else None
         if tracer is None:
             res = _assemble(codes, lengths, cfg, tracer=None)
@@ -158,6 +190,8 @@ def assemble(codes, lengths, cfg: PipelineConfig = PipelineConfig()) -> Assembly
         "hbm_source": wm.source,
         # what actually ran for every dispatched op (core/backend.py)
         "op_impls": {op: "+".join(sorted(v)) for op, v in impls.items()},
+        # blocking device→host reads of the driver below (obs.readback)
+        "host_readbacks": reads.n,
     }, context="assemble"))
     return res
 
@@ -179,13 +213,14 @@ def _assemble(codes, lengths, cfg: PipelineConfig, *, tracer) -> AssemblyResult:
             count_and_select(kmers, k=cfg.k, lower=cfg.lower,
                              upper=cfg.upper)
         )
+    m_reliable = int(readback(kc.m_reliable, "m_reliable"))
     metrics.emit_many({
-        "m_reliable": int(kc.m_reliable),
-        "n_unique_kmers": int(kc.n_unique),
-        "n_singletons": int(kc.n_singleton),
+        "m_reliable": m_reliable,
+        "n_unique_kmers": int(readback(kc.n_unique, "n_unique_kmers")),
+        "n_singletons": int(readback(kc.n_singleton, "n_singletons")),
     })
-    assert int(kc.m_reliable) <= cfg.m_capacity, (
-        f"m_capacity too small: {int(kc.m_reliable)} > {cfg.m_capacity}"
+    assert m_reliable <= cfg.m_capacity, (
+        f"m_capacity too small: {m_reliable} > {cfg.m_capacity}"
     )
 
     # --- CreateSpMat: A and Aᵀ ---
@@ -198,8 +233,8 @@ def _assemble(codes, lengths, cfg: PipelineConfig, *, tracer) -> AssemblyResult:
             kmer_capacity=cfg.upper,
         )
         sp.set_output((a.cols, at.cols))
-    metrics.emit("overflow_A", int(ovf_a))
-    metrics.emit("nnz_A", int(a.nnz()))
+    metrics.emit("overflow_A", int(readback(ovf_a, "overflow_A")))
+    metrics.emit("nnz_A", int(readback(a.nnz(), "nnz_A")))
 
     # --- SpGEMM: C = A·Aᵀ under the overlap semiring ---
     # distribution="shard_map" runs it on the explicit-exchange ring SUMMA
@@ -243,8 +278,8 @@ def _assemble(codes, lengths, cfg: PipelineConfig, *, tracer) -> AssemblyResult:
             metrics.emit("spgemm_row_chunk", min(row_chunk, int(n)))
         sp.set_output(c_mat.cols)
     metrics.seed_zero("summa_exchange")
-    metrics.emit("overflow_C", int(ovf_c))
-    metrics.emit("nnz_C", int(c_mat.nnz()))
+    metrics.emit("overflow_C", int(readback(ovf_c, "overflow_C")))
+    metrics.emit("nnz_C", int(readback(c_mat.nnz(), "nnz_C")))
     metrics.emit("c_density", metrics["nnz_C"] / max(1, int(n)))
 
     # --- Pairwise alignment on nnz(C) (upper triangle; each pair once) ---
@@ -272,7 +307,7 @@ def _assemble(codes, lengths, cfg: PipelineConfig, *, tracer) -> AssemblyResult:
         # count, align only the bucket (row-chunked), and scatter results
         # back to slot order.
         e_total = int(pair_i.shape[0])
-        n_live = int(jnp.sum(pv))
+        n_live = int(readback(jnp.sum(pv), "n_live"))
         bucket = next_pow2(n_live)
         idx = jnp.nonzero(pv, size=bucket, fill_value=0)[0]
         live = jnp.arange(bucket) < n_live
@@ -306,11 +341,11 @@ def _assemble(codes, lengths, cfg: PipelineConfig, *, tracer) -> AssemblyResult:
             metrics.emit_many(align_stats)
         else:
             def _align_block(blk):
-                ai = codes[blk["i"]]
-                bj = codes[blk["j"]]
-                bj = jnp.where(
-                    (blk["strand"] == 1)[:, None], revcomp(bj, blk["lj"]), bj
-                )
+                with jax.named_scope("align_staging"):
+                    ai = codes[blk["i"]]
+                    bj = codes[blk["j"]]
+                    bj = jnp.where((blk["strand"] == 1)[:, None],
+                                   revcomp(bj, blk["lj"]), bj)
                 out = al.batch_extend(
                     ai, blk["li"], bj, blk["lj"], blk["pa"], blk["pb"],
                     k=cfg.k, backend=backend, xdrop=cfg.xdrop,
@@ -347,7 +382,7 @@ def _assemble(codes, lengths, cfg: PipelineConfig, *, tracer) -> AssemblyResult:
         "n_aligned": n_live,
         "align_candidates": e_total,
         "align_bucket": int(bucket),
-        "n_passed": int(jnp.sum(passed)),
+        "n_passed": int(readback(jnp.sum(passed), "n_passed")),
     })
 
     # --- Build R: classify overlaps, drop contained ---
@@ -362,10 +397,11 @@ def _assemble(codes, lengths, cfg: PipelineConfig, *, tracer) -> AssemblyResult:
         )
         r_mat = drop_contained(r_mat, contained)
         sp.set_output(r_mat.cols)
-    metrics.emit("overflow_R", int(ovf_r))
-    metrics.emit("nnz_R", int(r_mat.nnz()))
+    metrics.emit("overflow_R", int(readback(ovf_r, "overflow_R")))
+    metrics.emit("nnz_R", int(readback(r_mat.nnz(), "nnz_R")))
     metrics.emit("r_density", metrics["nnz_R"] / max(1, int(n)))
-    metrics.emit("n_contained", int(jnp.sum(contained)))
+    metrics.emit("n_contained",
+                 int(readback(jnp.sum(contained), "n_contained")))
 
     # --- TrReduction: Algorithm 2 ---
     with _tic(timings, "TrReduction") as sp:
@@ -375,13 +411,15 @@ def _assemble(codes, lengths, cfg: PipelineConfig, *, tracer) -> AssemblyResult:
             backend=backend,
         )
         sp.set_output(s_mat.cols)
-    metrics.emit("tr_iterations", int(tr_stats.iterations))
+    metrics.emit("tr_iterations",
+                 int(readback(tr_stats.iterations, "tr_iterations")))
     # the kernel path that actually ran: transitive_reduction_fused silently
     # downgrades backend="pallas" to the sampled ELL square above
     # TR_DENSE_MAX_ROWS, and benchmark rows must label the real path
     metrics.emit("tr_backend", tr_stats.backend)
-    metrics.emit("tr_overflow", int(tr_stats.n_overflow))
-    metrics.emit("nnz_S", int(s_mat.nnz()))
+    metrics.emit("tr_overflow",
+                 int(readback(tr_stats.n_overflow, "tr_overflow")))
+    metrics.emit("nnz_S", int(readback(s_mat.nnz(), "nnz_S")))
     metrics.emit("s_density", metrics["nnz_S"] / max(1, int(n)))
 
     # --- Contigs (backend-dispatched: host walk or device path, §2.7;

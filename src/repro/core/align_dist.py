@@ -14,7 +14,7 @@ region with every exchanged word counted:
 2. **extend** — the local ``bucket/P`` candidate slice gathers its read
    rows, orients strand-1 partners with ``revcomp``, and runs
    ``assembly.alignment.batch_extend`` — the existing ``kernels/xdrop`` op
-   through the normal backend dispatch, so the op/kernel spans and the
+   through the normal backend dispatch, so the op spans and the
    reference↔pallas parity contract are untouched.
 3. **scatter_scores** — the five ``PairAlignment`` int32 outputs stack into
    one ``(5, bucket)`` buffer; each device writes only its own block

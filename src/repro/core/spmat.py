@@ -233,6 +233,7 @@ def _rank_in_row_sorted(rows_sorted: jnp.ndarray, kept: jnp.ndarray) -> jnp.ndar
 
 
 @partial(jax.jit, static_argnames=("n_rows", "n_cols", "capacity", "semiring"))
+@jax.named_scope("from_coo")
 def from_coo(
     rows: jnp.ndarray,
     cols: jnp.ndarray,
@@ -290,6 +291,7 @@ def from_coo(
     return EllMatrix(cols=out_cols, vals=out_vals, n_cols=n_cols), overflow
 
 
+@jax.named_scope("merge_sorted_rows")
 def merge_sorted_rows(
     cand_cols: jnp.ndarray, cand_vals: Any, *, capacity: int, semiring: Semiring
 ):
@@ -297,30 +299,41 @@ def merge_sorted_rows(
     and value pytree (n, Q, ...), sort each row by column, ⊕-combine duplicates
     and compact into an ELL row of ``capacity`` slots.
 
-    The workhorse of the local SpGEMM.  Returns (cols, vals, overflow)."""
+    The workhorse of the local SpGEMM.  Returns (cols, vals, overflow).  Its
+    device work runs under the ``merge_sorted_rows`` named scope, in three
+    parts (``sort``, ``combine``, ``compact``) that a profiler trace times
+    apart; the scopes are op metadata and change no computation."""
     n, q = cand_cols.shape
     big = np.int32(2**30)  # numpy scalar: stays a literal under Pallas tracing
-    key = jnp.where(cand_cols >= 0, cand_cols, big)
-    order = jnp.argsort(key, axis=1)
-    cs = jnp.take_along_axis(key, order, axis=1)
-    planes, _, join = _split_planes(cand_vals, 2)
-    vs = join([jnp.take_along_axis(p, order, axis=1) for p in planes])
-    valid = cs < big
-    prev = jnp.concatenate([jnp.full((n, 1), -2, cs.dtype), cs[:, :-1]], axis=1)
-    new_run = cs != prev
-    scanned = _segmented_combine(new_run, vs, semiring.add, axis=1)
-    next_new = jnp.concatenate([new_run[:, 1:], jnp.ones((n, 1), bool)], axis=1)
-    kept = next_new & valid & ~semiring.is_zero(scanned)
+    with jax.named_scope("sort"):
+        key = jnp.where(cand_cols >= 0, cand_cols, big)
+        order = jnp.argsort(key, axis=1)
+        cs = jnp.take_along_axis(key, order, axis=1)
+        planes, _, join = _split_planes(cand_vals, 2)
+        vs = join([jnp.take_along_axis(p, order, axis=1) for p in planes])
+    with jax.named_scope("combine"):
+        valid = cs < big
+        prev = jnp.concatenate([jnp.full((n, 1), -2, cs.dtype), cs[:, :-1]],
+                               axis=1)
+        new_run = cs != prev
+        scanned = _segmented_combine(new_run, vs, semiring.add, axis=1)
+        next_new = jnp.concatenate([new_run[:, 1:], jnp.ones((n, 1), bool)],
+                                   axis=1)
+        kept = next_new & valid & ~semiring.is_zero(scanned)
 
     # Compact: stable argsort moves kept entries (already col-ascending) first.
-    ckey = jnp.where(kept, cs, big)
-    order2 = jnp.argsort(ckey, axis=1)[:, :capacity]
-    out_cols_raw = jnp.take_along_axis(ckey, order2, axis=1)
-    out_cols = jnp.where(out_cols_raw < big, out_cols_raw.astype(jnp.int32), NO_COL)
-    planes, _, join = _split_planes(scanned, 2)
-    out_vals = join([jnp.take_along_axis(p, order2, axis=1) for p in planes])
-    out_vals = tree_where(out_cols >= 0, out_vals, semiring.zero((n, capacity)))
-    overflow = jnp.sum(jnp.maximum(jnp.sum(kept, axis=1) - capacity, 0))
+    with jax.named_scope("compact"):
+        ckey = jnp.where(kept, cs, big)
+        order2 = jnp.argsort(ckey, axis=1)[:, :capacity]
+        out_cols_raw = jnp.take_along_axis(ckey, order2, axis=1)
+        out_cols = jnp.where(out_cols_raw < big,
+                             out_cols_raw.astype(jnp.int32), NO_COL)
+        planes, _, join = _split_planes(scanned, 2)
+        out_vals = join([jnp.take_along_axis(p, order2, axis=1)
+                         for p in planes])
+        out_vals = tree_where(out_cols >= 0, out_vals,
+                              semiring.zero((n, capacity)))
+        overflow = jnp.sum(jnp.maximum(jnp.sum(kept, axis=1) - capacity, 0))
     return out_cols, out_vals, overflow
 
 

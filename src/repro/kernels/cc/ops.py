@@ -22,7 +22,6 @@ import jax.numpy as jnp
 
 from ...core.backend import note_impl, register_op
 from ...core.spmat import next_pow2
-from ...obs.trace import span
 from .cc import LANES, TILE, cc_rounds_pallas
 from .ref import cc_labels_ref
 
@@ -178,21 +177,19 @@ def cc_labels_pallas(
     cols_t = transpose_ell(cols)
     k_in = cols_t.shape[1]
     fused = _resident_bytes(n, k, k_in) <= VMEM_BUDGET_BYTES
-    with span("kernel_launch", kind="kernel", kernel="cc_labels",
-              fused=fused, n=n, k_out=k, k_in=k_in):
-        if not fused:
-            note_impl("reference (VMEM budget)")
-            return cc_labels_ref(cols, max_iters=max_iters)
-        rounds = max(1, min(rounds_per_call, max_iters))
-        n_chunks = max_iters // rounds
-        rem = max_iters % rounds
-        npad = _padded_rows(n)
-        lab, iters, _ = _drive_chunks(
-            _planes(cols), _planes(cols_t),
-            jnp.arange(npad, dtype=jnp.int32).reshape(npad // LANES, LANES),
-            rounds=rounds, n_chunks=n_chunks, rem=rem, interpret=interpret,
-        )
-        return lab.reshape(-1)[:n], iters
+    if not fused:
+        note_impl("reference (VMEM budget)")
+        return cc_labels_ref(cols, max_iters=max_iters)
+    rounds = max(1, min(rounds_per_call, max_iters))
+    n_chunks = max_iters // rounds
+    rem = max_iters % rounds
+    npad = _padded_rows(n)
+    lab, iters, _ = _drive_chunks(
+        _planes(cols), _planes(cols_t),
+        jnp.arange(npad, dtype=jnp.int32).reshape(npad // LANES, LANES),
+        rounds=rounds, n_chunks=n_chunks, rem=rem, interpret=interpret,
+    )
+    return lab.reshape(-1)[:n], iters
 
 
 def hbm_round_trips(iters: int, rounds_per_call: int = 8) -> int:

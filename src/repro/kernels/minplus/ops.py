@@ -8,7 +8,6 @@ kernel-side tuning knobs the dispatcher's callers never see.
 from __future__ import annotations
 
 from ...core.backend import register_op
-from ...obs.trace import span
 from .minplus import minplus_pallas
 from .ref import minplus_matmul_ref  # noqa: F401
 
@@ -16,12 +15,10 @@ from .ref import minplus_matmul_ref  # noqa: F401
 def minplus_matmul(a, b, *, block_m: int = 128, block_n: int = 128,
                    block_k: int = 128, interpret: bool | str = "auto"):
     """Dense orientation-resolved min-plus matmul on the Pallas kernel."""
-    with span("kernel_launch", kind="kernel", kernel="minplus_dense",
-              m=int(a.shape[0]), k=int(a.shape[1]), n=int(b.shape[1])):
-        return minplus_pallas(
-            a, b, block_m=block_m, block_n=block_n, block_k=block_k,
-            interpret=interpret,
-        )
+    return minplus_pallas(
+        a, b, block_m=block_m, block_n=block_n, block_k=block_k,
+        interpret=interpret,
+    )
 
 
 def _minplus_reference(a, b, *, block_m=None, block_n=None, block_k=None,

@@ -9,7 +9,6 @@ tuning knobs (``band``, ``interpret``).
 from __future__ import annotations
 
 from ...core.backend import register_op
-from ...obs.trace import span
 from .pileup import pileup_pallas
 from .ref import pileup_vote_ref  # noqa: F401
 
@@ -17,12 +16,10 @@ from .ref import pileup_vote_ref  # noqa: F401
 def pileup_vote(draft, pieces, start, plen, *, min_depth: int = 2,
                 band: int = 512, interpret: bool | str = "auto"):
     """Banded pileup + majority vote on the Pallas kernel (DESIGN.md §2.8)."""
-    with span("kernel_launch", kind="kernel", kernel="pileup_vote",
-              contigs=int(draft.shape[0]), band=band):
-        return pileup_pallas(
-            draft, pieces, start, plen, min_depth=min_depth, band=band,
-            interpret=interpret,
-        )
+    return pileup_pallas(
+        draft, pieces, start, plen, min_depth=min_depth, band=band,
+        interpret=interpret,
+    )
 
 
 def _pileup_reference(draft, pieces, start, plen, *, min_depth: int = 2,

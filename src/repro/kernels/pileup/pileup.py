@@ -203,24 +203,25 @@ def pileup_pallas(
                         memory_space=pltpu.SMEM)
     hbm = pl.BlockSpec(memory_space=pltpu.HBM)
     blk = pl.BlockSpec((None, 1, b), lambda i, j, k: (i, 0, j))
-    pol, dep, agr = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[scal, scal, hbm, hbm],
-        out_specs=[blk, blk, blk],
-        out_shape=[jax.ShapeDtypeStruct((c, 1, lp), jnp.int32)] * 3,
-        scratch_shapes=[
-            pltpu.VMEM((4, b), jnp.int32),
-            pltpu.VMEM((ROWS, b + 2 * LANES), jnp.uint8),
-            pltpu.VMEM((ROWS, b + 2 * LANES), jnp.uint8),
-            pltpu.SemaphoreType.DMA(()),
-        ],
-        interpret=interpret,
-    )(
-        jnp.pad(start.astype(jnp.int32), ((0, 0), (0, mp - m))).reshape(-1),
-        jnp.pad(plen.astype(jnp.int32), ((0, 0), (0, mp - m))).reshape(-1),
-        draft_p, pieces_p,
-    )
+    start_p = jnp.pad(start.astype(jnp.int32), ((0, 0), (0, mp - m)))
+    plen_p = jnp.pad(plen.astype(jnp.int32), ((0, 0), (0, mp - m)))
+    # the scope names the kernel's device time in the profiler trace
+    with jax.named_scope("pileup_vote"):
+        pol, dep, agr = pl.pallas_call(
+            kernel,
+            grid=grid,
+            in_specs=[scal, scal, hbm, hbm],
+            out_specs=[blk, blk, blk],
+            out_shape=[jax.ShapeDtypeStruct((c, 1, lp), jnp.int32)] * 3,
+            scratch_shapes=[
+                pltpu.VMEM((4, b), jnp.int32),
+                pltpu.VMEM((ROWS, b + 2 * LANES), jnp.uint8),
+                pltpu.VMEM((ROWS, b + 2 * LANES), jnp.uint8),
+                pltpu.SemaphoreType.DMA(()),
+            ],
+            interpret=interpret,
+            name="pileup_vote",
+        )(start_p.reshape(-1), plen_p.reshape(-1), draft_p, pieces_p)
     return (
         pol[:, 0, :l].astype(jnp.uint8), dep[:, 0, :l], agr[:, 0, :l],
     )

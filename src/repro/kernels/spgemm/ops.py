@@ -28,7 +28,6 @@ from ...core.backend import (
     resolve_interpret,
 )
 from ...core.semiring import Semiring
-from ...obs.trace import span
 from .ref import spgemm_ring_stages_ref
 from .spgemm import spgemm_ring_stages_pallas as _pallas_raw
 
@@ -142,19 +141,16 @@ def spgemm_ring_stages_pallas(
     fused, impl = stage_impl(a_cols, a_vals, b_cols, b_vals,
                              capacity=capacity, semiring=semiring,
                              interpret=interpret)
-    with span("kernel_launch", kind="kernel", kernel="spgemm_ring_stages",
-              fused=fused, stages=int(a_cols.shape[0]),
-              rows=int(a_cols.shape[1])):
-        if not fused:
-            note_impl(impl)
-            return spgemm_ring_stages_ref(
-                offsets, a_cols, a_vals, b_cols, b_vals, semiring=semiring,
-                capacity=capacity, n_cols_out=n_cols_out,
-            )
-        return _pallas_raw(
+    if not fused:
+        note_impl(impl)
+        return spgemm_ring_stages_ref(
             offsets, a_cols, a_vals, b_cols, b_vals, semiring=semiring,
-            capacity=capacity, n_cols_out=n_cols_out, interpret=interpret,
+            capacity=capacity, n_cols_out=n_cols_out,
         )
+    return _pallas_raw(
+        offsets, a_cols, a_vals, b_cols, b_vals, semiring=semiring,
+        capacity=capacity, n_cols_out=n_cols_out, interpret=interpret,
+    )
 
 
 def hbm_round_trips(stages: int, stages_per_call: int = 4) -> int:

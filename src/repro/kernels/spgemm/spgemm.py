@@ -206,14 +206,17 @@ def spgemm_ring_stages_pallas(
     out_shape = [
         jax.ShapeDtypeStruct((1, numel), dtype) for numel, dtype in out_elems
     ]
-    outs = pl.pallas_call(
-        kernel,
-        grid=(1,),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(*inputs)
+    # the scope names the kernel's device time in the profiler trace
+    with jax.named_scope("spgemm_ring_stages"):
+        outs = pl.pallas_call(
+            kernel,
+            grid=(1,),
+            in_specs=in_specs,
+            out_specs=out_specs,
+            out_shape=out_shape,
+            interpret=interpret,
+            name="spgemm_ring_stages",
+        )(*inputs)
 
     st_cols = outs[0].reshape(stages, n, capacity)
     st_leaves = [

@@ -8,7 +8,6 @@ core/backend.py): the oracle ignores the kernel-side tuning knobs
 from __future__ import annotations
 
 from ...core.backend import register_op, resolve_interpret
-from ...obs.trace import span
 from .xdrop import xdrop_pallas
 from .ref import xdrop_extend_batch_ref  # noqa: F401
 
@@ -24,12 +23,10 @@ def xdrop_extend_batch(a, base_a, step_a, len_a, b, base_b, step_b, len_b,
     HLO and one kernel instantiation)."""
     if pairs_per_block is None:
         pairs_per_block = int(a.shape[0]) if resolve_interpret(interpret) else 128
-    with span("kernel_launch", kind="kernel", kernel="xdrop_extend",
-              pairs=int(a.shape[0]), pairs_per_block=pairs_per_block):
-        return xdrop_pallas(
-            a, base_a, step_a, len_a, b, base_b, step_b, len_b,
-            pairs_per_block=max(1, pairs_per_block), interpret=interpret, **kw,
-        )
+    return xdrop_pallas(
+        a, base_a, step_a, len_a, b, base_b, step_b, len_b,
+        pairs_per_block=max(1, pairs_per_block), interpret=interpret, **kw,
+    )
 
 
 def _xdrop_reference(a, base_a, step_a, len_a, b, base_b, step_b, len_b,

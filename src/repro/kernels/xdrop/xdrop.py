@@ -133,14 +133,17 @@ def xdrop_pallas(
     def lanes(x):
         return jnp.pad(x.astype(jnp.int32), (0, pe - e)).reshape(1, pe)
 
-    u = jnp.arange(rows, dtype=jnp.int32)[:, None]
-    # a2[u] = a_text[(u − c) // 2]; b2[v] = b_text[(s_max + c − v) // 2]
-    a2 = _stage_text(a, base_a.astype(jnp.int32), step_a.astype(jnp.int32),
-                     (u - c) >> 1)
-    b2 = _stage_text(b, base_b.astype(jnp.int32), step_b.astype(jnp.int32),
-                     (s_max + c - u) >> 1)
-    a2 = jnp.pad(a2, ((0, 0), (0, pe - e)))
-    b2 = jnp.pad(b2, ((0, 0), (0, pe - e)))
+    # named scopes mark the device work in the profiler trace (op metadata)
+    with jax.named_scope("align_staging"):
+        u = jnp.arange(rows, dtype=jnp.int32)[:, None]
+        # a2[u] = a_text[(u − c) // 2]; b2[v] = b_text[(s_max + c − v) // 2]
+        a2 = _stage_text(a, base_a.astype(jnp.int32),
+                         step_a.astype(jnp.int32), (u - c) >> 1)
+        b2 = _stage_text(b, base_b.astype(jnp.int32),
+                         step_b.astype(jnp.int32), (s_max + c - u) >> 1)
+        a2 = jnp.pad(a2, ((0, 0), (0, pe - e)))
+        b2 = jnp.pad(b2, ((0, 0), (0, pe - e)))
+        la, lb = lanes(len_a), lanes(len_b)
 
     # inside a shard_map the outputs vary over whatever mesh axes the
     # inputs do (the distributed alignment region, core/align_dist.py)
@@ -156,16 +159,18 @@ def xdrop_pallas(
     seq = pl.BlockSpec((rows, pb), lambda i: (0, i))
     # two staged texts, double-buffered, plus headroom for the wavefront
     vmem = 4 * rows * pb * 4 + (4 << 20)
-    score, ai, bj = pl.pallas_call(
-        kernel,
-        grid=(pe // pb,),
-        in_specs=[row, row, seq, seq],
-        out_specs=[row, row, row],
-        out_shape=[jax.ShapeDtypeStruct((1, pe), jnp.int32, vma=vma)] * 3,
-        scratch_shapes=[pltpu.VMEM((2, wp, pb), jnp.int32)],
-        compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=max(vmem, 16 << 20)
-        ),
-        interpret=interpret,
-    )(lanes(len_a), lanes(len_b), a2, b2)
+    with jax.named_scope("xdrop_kernel"), jax.named_scope("xdrop_extend"):
+        score, ai, bj = pl.pallas_call(
+            kernel,
+            grid=(pe // pb,),
+            in_specs=[row, row, seq, seq],
+            out_specs=[row, row, row],
+            out_shape=[jax.ShapeDtypeStruct((1, pe), jnp.int32, vma=vma)] * 3,
+            scratch_shapes=[pltpu.VMEM((2, wp, pb), jnp.int32)],
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=max(vmem, 16 << 20)
+            ),
+            interpret=interpret,
+            name="xdrop_extend",
+        )(la, lb, a2, b2)
     return score[0, :e], ai[0, :e], bj[0, :e]

@@ -4,7 +4,7 @@ Three pieces (docs/observability.md):
 
 * ``obs.trace`` — hierarchical :func:`span` timing with device sync on
   exit; the single timing code path for pipeline stages, shard_map phases
-  and kernel launches.
+  and dispatched ops; :func:`readback`, the counted device→host read.
 * ``obs.schema`` / ``obs.metrics`` — the declared metric registry and the
   validating :class:`Metrics` accumulator the stats dicts emit through.
 * ``obs.export`` — Chrome trace-event / Perfetto JSON artifact writer.
@@ -14,7 +14,17 @@ Three pieces (docs/observability.md):
   result cache + append-only perf trajectory (``benchmarks/engine.py``).
 """
 
-from .trace import Span, Tracer, current_tracer, span, sync, tracing
+from .trace import (
+    Span,
+    ReadbackCount,
+    Tracer,
+    counting_readbacks,
+    current_tracer,
+    readback,
+    span,
+    sync,
+    tracing,
+)
 from .metrics import Metrics, MetricsError, validated
 from .export import span_tree, to_chrome_trace, write_chrome_trace
 from .memory import MemorySample, Watermark, sample, watermark
@@ -23,7 +33,10 @@ from . import schema
 __all__ = [
     "Span",
     "Tracer",
+    "ReadbackCount",
+    "counting_readbacks",
     "current_tracer",
+    "readback",
     "span",
     "sync",
     "tracing",

@@ -10,7 +10,7 @@ how Perfetto stacks them.
 
 Alongside ``traceEvents`` the file carries a ``spanTree`` key (ignored by
 trace viewers) with the explicit nesting — ``scripts/check_trace.py``
-asserts the stage → phase → kernel structure against it without having to
+asserts the stage → phase → op structure against it without having to
 re-derive containment from timestamps.
 """
 

@@ -78,6 +78,9 @@ _SPECS: Tuple[MetricSpec, ...] = (
        "device memory in use when the assemble window closed"),
     _l("hbm_source", "memory sampling path that produced the watermark "
        "(device_stats|live_buffers)"),
+    _c("host_readbacks", "reads",
+       "blocking device->host reads the pipeline driver took "
+       "(obs.trace.readback), one per read site reached"),
     # --- CountKmer ---
     _c("m_reliable", "kmers", "reliable k-mers kept (paper's |M|)"),
     _c("n_unique_kmers", "kmers", "distinct k-mers seen"),

@@ -10,8 +10,8 @@ One timing code path for the whole repo: a :func:`span` context manager that
   dataclasses like ``ContigSet``, which ``jax.block_until_ready`` treats as
   opaque leaves and silently skips;
 * nests: spans opened while another span is live become its children, so a
-  pipeline run produces a tree — stages → shard_map phases → kernel
-  launches.  Spans opened inside a ``jit``-traced function fire at *trace
+  pipeline run produces a tree — stages → shard_map phases → dispatched
+  ops.  Spans opened inside a ``jit``-traced function fire at *trace
   time* (host Python still runs), which is exactly when the nesting is
   meaningful; cached jits re-execute without re-tracing and therefore
   without re-emitting their inner spans (a fresh process — e.g. the CI
@@ -24,16 +24,25 @@ Spans work with or without an active :class:`Tracer`: without one they
 still time and sync (that is what keeps ``_tic`` a thin wrapper), they are
 just not recorded.  Activate a tracer for a region with :func:`tracing`;
 export the recorded tree with ``obs.export``.
+
+Spans name host work.  Device work is named by ``jax.named_scope`` inside
+the traced functions, which lands in each HLO op's ``op_name`` metadata
+and so in the profiler's device trace.  Between the two sits
+:func:`readback`, the one path for a blocking device→host read: it counts
+every read (:func:`counting_readbacks`) and, under an annotating tracer,
+names the read in the profiler trace.
 """
 
 from __future__ import annotations
 
 import contextlib
+import contextvars
 import dataclasses
 import time
 from typing import Any, Dict, Iterator, List, Optional
 
 import jax
+import numpy as np
 
 
 def _device_leaves(obj: Any, seen: set) -> list:
@@ -227,3 +236,48 @@ def span(name: str, **attrs: Any):
             ann.__exit__(None, None, None)
         if tracer is not None:
             tracer._pop(sp)
+
+
+
+@dataclasses.dataclass
+class ReadbackCount:
+    """Blocking device→host reads taken through :func:`readback`."""
+
+    n: int = 0
+
+
+_READBACKS: contextvars.ContextVar[Optional[ReadbackCount]] = (
+    contextvars.ContextVar("readbacks", default=None)
+)
+
+
+@contextlib.contextmanager
+def counting_readbacks() -> Iterator[ReadbackCount]:
+    """Count every :func:`readback` of a device value made inside the
+    block; yields the :class:`ReadbackCount`."""
+    count = ReadbackCount()
+    token = _READBACKS.set(count)
+    try:
+        yield count
+    finally:
+        _READBACKS.reset(token)
+
+
+def readback(x: Any, site: str) -> np.ndarray:
+    """``x`` on the host as a numpy value.
+
+    A device array is a blocking read: it is counted by the enclosing
+    :func:`counting_readbacks`, and while an annotating tracer is active it
+    runs under ``jax.profiler.TraceAnnotation("readback:<site>")``, so the
+    device idle gap it leaves is named in a profiler capture.  A value
+    already on the host passes through uncounted."""
+    if not isinstance(x, jax.Array):
+        return np.asarray(x)
+    count = _READBACKS.get()
+    if count is not None:
+        count.n += 1
+    tracer = _ACTIVE
+    if tracer is None or not tracer.annotate:
+        return np.asarray(x)
+    with jax.profiler.TraceAnnotation(f"readback:{site}"):
+        return np.asarray(x)

@@ -12,6 +12,7 @@ library: under pytest-xdist only the worker that runs this file does.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -163,3 +164,48 @@ def test_spgemm_ring_stages_tpu_path_compiles_for_v5e(one_chip, no_cache):
     assert "tpu_custom_call" not in compiled.as_text()
     with pytest.raises((MosaicError, NotImplementedError, ValueError)):
         _compile(lambda *a: fused_kernel(*a, interpret=False, **kw), *shapes)
+
+
+def _small_kernel_call(kernel, s):
+    """``(fn, shapes)`` of one Pallas kernel at a small v5e-legal size."""
+    if kernel == "xdrop_extend":
+        from repro.kernels.xdrop.xdrop import xdrop_pallas
+
+        seq, vec = s((128, 256), jnp.uint8), s((128,))
+        return (lambda *a: xdrop_pallas(*a, band=33, max_steps=256,
+                                        interpret=False),
+                (seq, vec, vec, vec, seq, vec, vec, vec))
+    if kernel == "pileup_vote":
+        from repro.kernels.pileup.pileup import pileup_pallas
+
+        c, m = 8, 4096  # one SMEM chunk of piece starts per contig
+        return (lambda *a: pileup_pallas(*a, min_depth=2, band=512,
+                                         interpret=False),
+                (s((c, 4096), jnp.uint8), s((c, m, 256), jnp.uint8),
+                 s((c, m)), s((c, m))))
+    if kernel == "cc_labels":
+        from repro.kernels.cc.cc import LANES, cc_rounds_pallas
+
+        return (lambda a, b, lab: cc_rounds_pallas(a, b, lab, rounds=2,
+                                                   interpret=False),
+                (s((4, 8, LANES)), s((4, 8, LANES)), s((8, LANES))))
+    from repro.kernels.minplus.minplus import minplus_pallas
+
+    return (lambda a, b: minplus_pallas(a, b, interpret=False),
+            (s((256, 256, 4), jnp.float32), s((256, 256, 4), jnp.float32)))
+
+
+@pytest.mark.parametrize("kernel", ["xdrop_extend", "pileup_vote",
+                                    "cc_labels", "minplus_dense"])
+def test_kernel_custom_call_carries_its_name(kernel, one_chip, no_cache):
+    """Each Mosaic custom call is named for its kernel (``pallas_call``'s
+    ``name=``) and sits under a named scope of the same name, so the
+    profiler's device trace finds the kernel by name."""
+    fn, shapes = _small_kernel_call(kernel, _sds(one_chip))
+    text = _compile(fn, *shapes).as_text()
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert calls
+    for ln in calls:
+        assert re.match(rf"\s*(ROOT )?%{kernel}[.\d]* = ", ln), ln[:120]
+        assert f"/{kernel}/" in ln, ln[:120]

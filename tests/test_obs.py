@@ -37,7 +37,7 @@ def test_span_nesting_builds_tree():
     with tracing(tr):
         with span("Stage", kind="stage"):
             with span("Phase", kind="phase", phase="ring_stage"):
-                with span("kernel_launch", kind="kernel"):
+                with span("op:xdrop_extend", kind="op"):
                     pass
             with span("Phase", kind="phase", phase="merge"):
                 pass
@@ -47,7 +47,7 @@ def test_span_nesting_builds_tree():
     stage = tr.roots[0]
     assert [c.attrs["phase"] for c in stage.children] == ["ring_stage",
                                                           "merge"]
-    assert stage.children[0].children[0].name == "kernel_launch"
+    assert stage.children[0].children[0].name == "op:xdrop_extend"
     assert all(sp.duration_s >= 0 for sp in tr.spans())
     assert len(tr.find("Phase")) == 2
 
@@ -248,6 +248,100 @@ def test_untraced_assemble_has_no_tracer():
     res = assemble(rs.codes, rs.lengths,
                    PipelineConfig(backend="reference", polish=False))
     assert res.trace is None
+
+
+# ---------------------------------------------------------------------------
+# host readbacks
+# ---------------------------------------------------------------------------
+
+
+def test_readback_counts_device_values_and_skips_host_values():
+    from repro.obs import counting_readbacks, readback
+
+    with counting_readbacks() as reads:
+        assert int(readback(jnp.int32(3), "scalar")) == 3
+        np.testing.assert_array_equal(readback(jnp.arange(4), "array"),
+                                      np.arange(4))
+        assert reads.n == 2
+        assert int(readback(5, "host_int")) == 5
+        np.testing.assert_array_equal(readback(np.arange(3), "host_array"),
+                                      np.arange(3))
+    assert reads.n == 2
+    assert int(readback(jnp.int32(1), "uncounted")) == 1  # no counter open
+    assert reads.n == 2
+
+
+def test_readback_names_its_site_under_an_annotating_tracer(monkeypatch):
+    import jax
+
+    from repro.obs import readback
+
+    opened = []
+
+    class Annotation:
+        def __init__(self, name):
+            opened.append(name)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
+    readback(jnp.int32(1), "untraced")
+    with tracing(Tracer(annotate=False, memory=False)):
+        readback(jnp.int32(1), "not_annotated")
+    with tracing(Tracer(annotate=True, memory=False)):
+        readback(jnp.int32(1), "n_live")
+        readback(7, "host_value")
+    assert opened == ["readback:n_live"]
+
+
+def test_assemble_counts_host_readbacks_traced_or_not():
+    """Every read site of the pipeline driver is reached once per job,
+    traced or not."""
+    import inspect
+
+    from repro.assembly import pipeline
+    from repro.assembly.simulate import simulate_genome, simulate_reads
+
+    rng = np.random.default_rng(7)
+    g = simulate_genome(rng, 1200)
+    rs = simulate_reads(g, depth=5, mean_len=300, std_len=30, min_len=200,
+                        seed=9)
+    counts = [
+        pipeline.assemble(rs.codes, rs.lengths, pipeline.PipelineConfig(
+            backend="reference", polish=False, trace=trace,
+        )).stats["host_readbacks"]
+        for trace in (False, True)
+    ]
+    sites = inspect.getsource(pipeline._assemble).count("readback(")
+    assert counts == [sites, sites] and sites > 0
+
+
+def test_assemble_keys_the_compile_cache_on_op_names(monkeypatch):
+    """A profile is read by its op names, so assemble() never reuses an
+    executable cached under other names; the settings are restored after."""
+    import jax
+
+    from repro.assembly import pipeline
+
+    flags = ("jax_compilation_cache_include_metadata_in_key",
+             "jax_traceback_in_locations_limit")
+    seen = []
+
+    def fake(codes, lengths, cfg, *, tracer):
+        seen.append(tuple(getattr(jax.config, f) for f in flags))
+        return pipeline.AssemblyResult(None, None, [], {}, {})
+
+    monkeypatch.setattr(pipeline, "_assemble", fake)
+    before = tuple(getattr(jax.config, f) for f in flags)
+    for trace in (False, True):
+        pipeline.assemble(np.zeros((2, 8), np.uint8), np.full(2, 8),
+                          pipeline.PipelineConfig(trace=trace))
+    assert seen == [(True, 1)] * 2
+    assert tuple(getattr(jax.config, f) for f in flags) == before
 
 
 # ---------------------------------------------------------------------------

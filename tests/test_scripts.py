@@ -237,9 +237,7 @@ def _valid_tree(ctr):
 
     spgemm_children = [
         phase("skew"),
-        phase("ring", [phase("ring_stage",
-                             [_node("op", "op",
-                                    [_node("k", "kernel", kernel="mp")])])]),
+        phase("ring", [phase("ring_stage", [_node("op", "op")])]),
         phase("collect_merge"),
     ]
     contig_children = [phase("chain_stage",
@@ -247,10 +245,7 @@ def _valid_tree(ctr):
                               phase("sort")])]
     align_children = [phase("pair_exchange",
                             [phase("gather_reads"),
-                             phase("extend",
-                                   [_node("op", "op",
-                                          [_node("k", "kernel",
-                                                 kernel="xdrop")])]),
+                             phase("extend", [_node("op", "op")]),
                              phase("scatter_scores")])]
     tree = []
     for name in ctr.STAGES:
@@ -299,13 +294,6 @@ def test_ctr_missing_align_phase_fails(ctr):
     msgs = ctr.check(tree)
     for ph in ("pair_exchange", "gather_reads", "extend", "scatter_scores"):
         assert any(f"phase={ph!r}" in m and "Alignment" in m for m in msgs)
-
-
-def test_ctr_kernel_outside_op_fails(ctr):
-    tree = _valid_tree(ctr)
-    tree[0]["children"] = [_node("stray", "kernel", kernel="x")]
-    msgs = ctr.check(tree)
-    assert any("bypassed the dispatch layer" in m for m in msgs)
 
 
 def test_ctr_main_exit_codes(tmp_path, ctr, capsys):
