@@ -138,8 +138,8 @@ def build_matrices(
 
     # A: each read's row sorted by column (stable: equal columns keep their
     # instance order), the first instance of each column kept, then the
-    # first ``read_capacity`` kept columns.  Two single-key row sorts; the
-    # argsort + gather form of ``merge_sorted_rows`` took XLA's TPU
+    # first ``read_capacity`` kept columns.  Two single-key row sorts that
+    # carry the positions: an argsort + row gather form took XLA's TPU
     # compiler minutes at these widths.
     big = jnp.int32(2**30)
     w = max(p, read_capacity)  # rows narrower than the capacity are padded

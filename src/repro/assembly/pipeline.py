@@ -20,6 +20,7 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import functools
+import math
 from typing import Any, Dict, Optional
 
 import jax
@@ -56,11 +57,14 @@ from .counter import build_matrices, count_and_select
 from .kmers import extract_kmers, revcomp
 
 
-# candidates (rows × K_A × K_B) the local overlap SpGEMM expands and sorts
-# at once, at most.  At K_A·K_B = 8960 that is 4096-row blocks, for which
-# XLA's v5e compile reserves 2.1 GB of temporaries (4.7 GB for 10,000 rows
-# unblocked; an E. coli-length run unblocked would not fit 16 GB of HBM).
-SPGEMM_CANDIDATES = 1 << 26
+# 4-byte words the local overlap SpGEMM's merge sorts at once, at most: each
+# of rows × K_A × K_B candidates carries its column key and one word per
+# value plane through the row sorts.  2^27 words held 2^26 candidates when
+# the sort carried a key and an index; at K_A·K_B = 8960 that is 4096-row
+# blocks, for which XLA's v5e compile reserved 2.1 GB of temporaries (4.7 GB
+# for 10,000 rows unblocked; an E. coli-length run unblocked would not fit
+# 16 GB of HBM).
+SPGEMM_SORT_WORDS = 1 << 27
 
 
 @dataclasses.dataclass
@@ -264,11 +268,13 @@ def _assemble(codes, lengths, cfg: PipelineConfig, *, tracer) -> AssemblyResult:
             metrics.emit("overlap_distribution", "shard_map")
             metrics.emit_many(summa_stats)
         else:
-            # the candidate expand/sort buffer holds rows·K_A·K_B entries;
-            # past SPGEMM_CANDIDATES it runs in row blocks (per-row
+            # the merge sorts rows·K_A·K_B candidates of a few words each;
+            # past SPGEMM_SORT_WORDS it runs in row blocks (per-row
             # identical) so a bacterial genome's buffer fits in HBM
-            per_row = a.cols.shape[1] * at.cols.shape[1]
-            row_chunk = 1 << (max(1, SPGEMM_CANDIDATES // per_row)
+            planes = jax.tree.leaves(overlap_semiring.zero((1,)))
+            words = 1 + sum(math.prod(p.shape[1:]) for p in planes)
+            per_row = a.cols.shape[1] * at.cols.shape[1] * words
+            row_chunk = 1 << (max(1, SPGEMM_SORT_WORDS // per_row)
                               .bit_length() - 1)
             c_mat, ovf_c = spgemm(
                 a, at, semiring=overlap_semiring,

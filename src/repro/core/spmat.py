@@ -200,8 +200,26 @@ def _segmented_combine(flags: jnp.ndarray, vals: Any, add, axis: int = 0) -> Any
         return (fx | fy, split(tree_where(fy, vy, add(vx, vy))))
 
     if flags.ndim > 1:
-        _, out = jax.lax.associative_scan(op, (flags, planes), axis=axis)
-        return join(out)
+        # Rows (the candidate merge): the same doubling steps unrolled, each
+        # shifting the row by a static 2^i.  associative_scan's strided
+        # slices along rows of sorted candidates took XLA's TPU code
+        # generation minutes (seconds when the rows came from gathers).
+        q = flags.shape[axis]
+        idx = jax.lax.broadcasted_iota(jnp.int32, flags.shape, axis)
+        f, p = flags, planes
+        for i in range(max(1, (q - 1).bit_length())):
+            s = 1 << i
+
+            def back_by(x, s=s):
+                return jnp.concatenate(
+                    [jax.lax.slice_in_dim(x, 0, s, axis=axis),
+                     jax.lax.slice_in_dim(x, 0, q - s, axis=axis)], axis=axis)
+
+            back = idx >= s
+            fb, vb = op((back_by(f), [back_by(x) for x in p]), (f, p))
+            f = jnp.where(back, fb, f)
+            p = [jnp.where(back, b, x) for b, x in zip(vb, p)]
+        return join(p)
     # One long axis (from_coo): log2(n) doubling steps in a loop, each
     # combining with the element 2^i back.  associative_scan unrolls its
     # levels over slices whose XLA TPU compile time grows with the length
@@ -302,15 +320,27 @@ def merge_sorted_rows(
     The workhorse of the local SpGEMM.  Returns (cols, vals, overflow).  Its
     device work runs under the ``merge_sorted_rows`` named scope, in three
     parts (``sort``, ``combine``, ``compact``) that a profiler trace times
-    apart; the scopes are op metadata and change no computation."""
+    apart; the scopes are op metadata and change no computation.
+
+    ``sort`` carries the value planes through stable row sorts keyed on the
+    column, one (column, plane) sort per plane: equal columns keep their
+    candidate order in each, so every plane gets the same permutation, on
+    which an order-dependent ⊕ (the overlap semiring's first position pairs)
+    relies.  Applying an argsort's order by gathers along the rows gives the
+    same result at many times the TPU time.  XLA's TPU compiler lays a row
+    sort of more than two operands (stability adds an index) along the
+    lanes, whose code generation took minutes at these widths, and merges
+    sorts that share a key into one; the barriers keep the sorts apart."""
     n, q = cand_cols.shape
     big = np.int32(2**30)  # numpy scalar: stays a literal under Pallas tracing
     with jax.named_scope("sort"):
         key = jnp.where(cand_cols >= 0, cand_cols, big)
-        order = jnp.argsort(key, axis=1)
-        cs = jnp.take_along_axis(key, order, axis=1)
         planes, _, join = _split_planes(cand_vals, 2)
-        vs = join([jnp.take_along_axis(p, order, axis=1) for p in planes])
+        pairs = [jax.lax.sort(jax.lax.optimization_barrier((key, p)),
+                              dimension=1, num_keys=1, is_stable=True)
+                 for p in planes]
+        cs = pairs[0][0]
+        vs = join([v for _, v in pairs])
     with jax.named_scope("combine"):
         valid = cs < big
         prev = jnp.concatenate([jnp.full((n, 1), -2, cs.dtype), cs[:, :-1]],

@@ -13,9 +13,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.semiring import count_semiring as CS
+from repro.core.semiring import count_semiring as CS, overlap_semiring
 from repro.core.spgemm import spgemm
-from repro.core.spmat import from_coo, merge_sorted_rows
+from repro.core.spmat import EllMatrix, from_coo, merge_sorted_rows
 from repro.kernels.xdrop.xdrop import xdrop_pallas
 
 MERGE = ["merge_sorted_rows/sort", "merge_sorted_rows/combine",
@@ -93,3 +93,34 @@ def test_expand_and_merge_scopes_do_not_nest():
     names = re.findall(r'op_name="([^"]*)"', text)
     assert not [n for n in names
                 if "spgemm_expand" in n and "merge_sorted_rows" in n]
+
+
+def test_merge_sort_carries_value_planes_without_gathers():
+    """The merge's ``sort`` scope carries every value plane through a row
+    sort keyed on the column, one (key, plane) sort per plane: no gather
+    applies a sort order to the candidates there (on a TPU such full-width
+    gathers cost many times the sort), and no sort there has more than two
+    operands (XLA's TPU compiler lays such a sort along the lanes, and its
+    code generation takes minutes at the pipeline's widths)."""
+    rng = np.random.default_rng(0)
+
+    def mat(n, m, k):
+        cols = np.sort(rng.integers(0, m, (n, k)), axis=1)
+        pos = rng.integers(0, 99, (n, k))
+        return EllMatrix(cols=jnp.asarray(cols, jnp.int32),
+                         vals={"pos": jnp.asarray(pos, jnp.int32)}, n_cols=m)
+
+    text = spgemm.lower(mat(12, 9, 4), mat(9, 11, 5),
+                        semiring=overlap_semiring,
+                        capacity=6).compile().as_text()
+    under_sort = [line for line in text.splitlines()
+                  if "/merge_sorted_rows/sort/" in line]
+    assert under_sort, "the merge_sorted_rows/sort scope is gone"
+    assert not [line for line in under_sort if " gather(" in line]
+    # cnt + apos (2) + bpos (2) planes, each an (n, K_A·K_B) operand beside
+    # the key
+    sorts = [re.match(r"\s*%\S+ = \((.*?)\) sort\(", line)
+             for line in under_sort]
+    widths = [len(re.findall(r"s32\[12,20\]", m.group(1)))
+              for m in sorts if m]
+    assert widths == [2] * 5, widths

@@ -7,12 +7,26 @@ pinned directly here rather than only through end-to-end parity."""
 from collections import Counter
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 from _hypothesis_compat import given, settings, st
 
-from repro.core.semiring import count_semiring as CS
-from repro.core.spmat import EllMatrix, from_coo, merge_sorted_rows, prune
+from repro.core.semiring import (
+    NUM_POS_PAIRS,
+    count_semiring as CS,
+    minplus_orient_semiring,
+    overlap_semiring,
+    tree_where,
+)
+from repro.core.spmat import (
+    EllMatrix,
+    NO_COL,
+    _split_planes,
+    from_coo,
+    merge_sorted_rows,
+    prune,
+)
 
 
 @settings(max_examples=30, deadline=None)
@@ -156,3 +170,101 @@ def test_merge_sorted_rows_matches_dedup_oracle(cols_list, capacity):
     assert cols.tolist() == [exp_cols]
     assert vals.tolist() == [exp_vals]
     assert int(ovf) == max(len(distinct) - capacity, 0)
+
+
+def _scan_runs(flags, vals, add):
+    """Segmented inclusive ⊕-scan along rows by ``associative_scan``."""
+    planes, split, join = _split_planes(vals, 2)
+
+    def op(x, y):
+        (fx, px), (fy, py) = x, y
+        vx, vy = join(px), join(py)
+        return fx | fy, split(tree_where(fy, vy, add(vx, vy)))
+
+    return join(jax.lax.associative_scan(op, (flags, planes), axis=1)[1])
+
+
+def _merge_by_gathers(cand_cols, cand_vals, *, capacity, semiring):
+    """Reference merge: an argsort of the column key, its order applied to
+    the key and each value plane by gathers along the rows, runs combined by
+    ``associative_scan``, then the capacity compaction."""
+    n, _ = cand_cols.shape
+    big = np.int32(2**30)
+    key = jnp.where(cand_cols >= 0, cand_cols, big)
+    order = jnp.argsort(key, axis=1)
+    cs = jnp.take_along_axis(key, order, axis=1)
+    planes, _, join = _split_planes(cand_vals, 2)
+    vs = join([jnp.take_along_axis(p, order, axis=1) for p in planes])
+    valid = cs < big
+    prev = jnp.concatenate([jnp.full((n, 1), -2, cs.dtype), cs[:, :-1]],
+                           axis=1)
+    new_run = cs != prev
+    scanned = _scan_runs(new_run, vs, semiring.add)
+    next_new = jnp.concatenate([new_run[:, 1:], jnp.ones((n, 1), bool)],
+                               axis=1)
+    kept = next_new & valid & ~semiring.is_zero(scanned)
+    ckey = jnp.where(kept, cs, big)
+    order2 = jnp.argsort(ckey, axis=1)[:, :capacity]
+    out_raw = jnp.take_along_axis(ckey, order2, axis=1)
+    out_cols = jnp.where(out_raw < big, out_raw, NO_COL)
+    planes, _, join = _split_planes(scanned, 2)
+    out_vals = join([jnp.take_along_axis(p, order2, axis=1) for p in planes])
+    out_vals = tree_where(out_cols >= 0, out_vals,
+                          semiring.zero((n, capacity)))
+    overflow = jnp.sum(jnp.maximum(jnp.sum(kept, axis=1) - capacity, 0))
+    return out_cols, out_vals, overflow
+
+
+def _cols(rng, n, q, n_cols, pad_share):
+    cols = rng.integers(0, n_cols, (n, q))
+    cols = np.where(rng.random((n, q)) < pad_share, -1, cols).astype(np.int32)
+    cols[5] = 1  # one run the width of the row: every step of the scan
+    return cols
+
+
+def _count_case(rng):
+    # 12 columns into capacity 8: ties in every row, overflow in most;
+    # zero values test the is_zero drop; one row all pads
+    cols = _cols(rng, 16, 64, 12, 0.25)
+    cols[3] = -1
+    vals = rng.integers(0, 4, cols.shape).astype(np.int32)
+    return CS, cols, jnp.asarray(vals), 8
+
+
+def _overlap_case(rng):
+    # 6 columns over 128 candidates a row: ~20 duplicates a column, each
+    # with its own position pairs, so which NUM_POS_PAIRS pairs survive ⊕
+    # depends on the merge keeping candidate order within a column
+    cols = _cols(rng, 16, 128, 6, 0.1)
+    shape = cols.shape + (2,)
+    vals = {"cnt": jnp.asarray(rng.integers(1, 3, cols.shape), jnp.int32),
+            "apos": jnp.asarray(rng.integers(0, 10_000, shape), jnp.int32),
+            "bpos": jnp.asarray(rng.integers(0, 10_000, shape), jnp.int32)}
+    return overlap_semiring, cols, vals, 4
+
+
+def _minplus_case(rng):
+    # float planes with absent (inf) orientations and whole-inf candidates
+    cols = _cols(rng, 16, 64, 10, 0.2)
+    vals = rng.random(cols.shape + (4,)).astype(np.float32) * 100
+    vals = np.where(rng.random(vals.shape) < 0.5, np.inf, vals)
+    return minplus_orient_semiring, cols, jnp.asarray(vals), 6
+
+
+@pytest.mark.parametrize("case", [_count_case, _overlap_case, _minplus_case],
+                         ids=["count", "overlap", "minplus_orient"])
+def test_merge_sorted_rows_bit_equal_to_gather_merge(case):
+    semiring, cols, vals, capacity = case(np.random.default_rng(14))
+    got = jax.jit(lambda c, v: merge_sorted_rows(
+        c, v, capacity=capacity, semiring=semiring))(jnp.asarray(cols), vals)
+    want = jax.jit(lambda c, v: _merge_by_gathers(
+        c, v, capacity=capacity, semiring=semiring))(jnp.asarray(cols), vals)
+    got_leaves, want_leaves = jax.tree.leaves(got), jax.tree.leaves(want)
+    assert len(got_leaves) == len(want_leaves)
+    for g, w in zip(got_leaves, want_leaves):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        assert np.array_equal(np.asarray(g), np.asarray(w))
+    if semiring is CS:  # the capacity cut is exercised
+        assert int(got[2]) > 0
+    if semiring is overlap_semiring:  # ⊕ drops position pairs
+        assert int(jnp.max(got[1]["cnt"])) > NUM_POS_PAIRS
